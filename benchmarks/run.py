@@ -27,6 +27,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
+
 
 def _time_call(fn, *args, iters=5, warmup=2) -> float:
     for _ in range(warmup):
@@ -1070,6 +1072,7 @@ def main() -> None:
                          "suites are selected, each gets a derived sibling "
                          "path: --out F.json -> F.engine.json, ...")
     args = ap.parse_args()
+    compile_cache.enable()
     if args.only:
         names = [args.only]
     elif args.suite:

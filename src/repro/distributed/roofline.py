@@ -1,11 +1,10 @@
 """Three-term roofline model for every (arch x shape x mesh) cell.
 
-TPU v5e constants (per chip): 197 bf16 TFLOP/s, 819 GB/s HBM, ~50 GB/s/link
-ICI.
+Per-chip peaks live in ``PEAKS``, keyed by ``jax.Device.device_kind``:
 
   compute term    = device_FLOPs / peak_FLOP/s
   memory term     = device_HBM_bytes / HBM_bw
-  collective term = device_collective_bytes / link_bw
+  collective term = device_collective_bytes / ICI_bw
 
 Because XLA's ``cost_analysis`` counts ``while`` (scan) bodies once, the
 compute and memory terms are built ANALYTICALLY from the model config and
@@ -22,9 +21,39 @@ import dataclasses
 from repro.configs.registry import ShapeCell
 from repro.models.common import ModelConfig
 
-PEAK_FLOPS = 197e12        # bf16 / chip
-HBM_BW = 819e9             # bytes/s / chip
-ICI_BW = 50e9              # bytes/s / link (per-device collective bandwidth)
+
+@dataclasses.dataclass(frozen=True)
+class Peaks:
+    """Published per-chip peaks."""
+
+    flops: float     # bf16 FLOP/s
+    hbm_bw: float    # HBM bytes/s
+    ici_bw: float    # chip-to-chip interconnect bytes/s
+
+
+# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM,
+# 1,600 Gbit/s chip-to-chip interconnect.
+PEAKS = {
+    "TPU v5 lite": Peaks(flops=197e12, hbm_bw=819e9, ici_bw=1600e9 / 8),
+}
+
+# The chip the analytic model targets where no TPU is attached: the dry
+# run's placeholder devices, and CPU runs that rank tiles or attribute cost.
+MODEL_PEAKS = PEAKS["TPU v5 lite"]
+
+
+def device_peaks() -> Peaks:
+    """Peaks of the first JAX device: ``MODEL_PEAKS`` off-TPU; a TPU kind
+    missing from ``PEAKS`` is an error, never another chip's numbers."""
+    import jax
+
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return MODEL_PEAKS
+    if dev.device_kind not in PEAKS:
+        raise KeyError(f"no roofline peaks for TPU kind {dev.device_kind!r}; "
+                       f"add its published peaks to PEAKS")
+    return PEAKS[dev.device_kind]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -304,7 +333,8 @@ class VqCell:
 
 
 def vq_roofline_terms(cell: VqCell,
-                      collective_bytes_per_window: float | None = None) -> dict:
+                      collective_bytes_per_window: float | None = None,
+                      peaks: Peaks = MODEL_PEAKS) -> dict:
     """Per-window roofline terms (seconds) for one VQ worker-device.
 
     ``collective_bytes_per_window`` should come from the trip-count-
@@ -317,9 +347,9 @@ def vq_roofline_terms(cell: VqCell,
             if collective_bytes_per_window is None
             else collective_bytes_per_window)
     terms = {
-        "compute": cell.window_flops() / PEAK_FLOPS,
-        "memory": cell.window_hbm_bytes() / HBM_BW,
-        "collective": coll / ICI_BW,
+        "compute": cell.window_flops() / peaks.flops,
+        "memory": cell.window_hbm_bytes() / peaks.hbm_bw,
+        "collective": coll / peaks.ici_bw,
     }
     dominant = max(terms, key=terms.get)
     return {
@@ -343,15 +373,15 @@ def roofline_terms(cfg: ModelConfig, cell: ShapeCell, mesh: MeshShape,
     dev_flops = fl["total"] * waste / mesh.n_devices
     by = cell_bytes(cfg, cell, mesh)
 
-    t_compute = dev_flops / PEAK_FLOPS
-    t_memory = by["total"] / HBM_BW
-    t_coll = collective_bytes_per_dev / ICI_BW
+    t_compute = dev_flops / MODEL_PEAKS.flops
+    t_memory = by["total"] / MODEL_PEAKS.hbm_bw
+    t_coll = collective_bytes_per_dev / MODEL_PEAKS.ici_bw
     terms = {"compute": t_compute, "memory": t_memory,
              "collective": t_coll}
     dominant = max(terms, key=terms.get)
     step_time = max(terms.values())  # perfect-overlap bound
-    mfu = (fl["model_flops"] / mesh.n_devices / PEAK_FLOPS) / step_time \
-        if step_time > 0 else 0.0
+    mfu = ((fl["model_flops"] / mesh.n_devices / MODEL_PEAKS.flops)
+           / step_time if step_time > 0 else 0.0)
     return {
         **{f"t_{k}": v for k, v in terms.items()},
         "dominant": dominant,

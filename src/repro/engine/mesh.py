@@ -47,7 +47,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import comm, compat
+from repro import comm
 from repro.core import vq
 from repro.core.schemes import SchemeResult
 from repro.engine import api, merge as merge_lib
@@ -281,7 +281,7 @@ class MeshExecutor:
                     compiled = fn.lower(*args).compile()
                     try:
                         cost = compiled.cost_analysis()
-                    except Exception:       # backend without cost support
+                    except NotImplementedError:   # backend has no estimate
                         cost = None
                     self.profiler.record_program(
                         cache_key, compiled.as_text(), cost)
@@ -625,8 +625,8 @@ class MeshExecutor:
             in_specs = (P(), P(), P(axis), P(axis), P(axis))
             if quorum:
                 in_specs += (P(axis),)
-            return jax.jit(compat.shard_map(
-                body, mesh,
+            return jax.jit(jax.shard_map(
+                body, mesh=mesh,
                 in_specs=in_specs,
                 out_specs=out_specs,
                 axis_names=frozenset(axes), check_vma=False))
@@ -884,8 +884,8 @@ class MeshExecutor:
                      vmem_budget)
 
         def build():
-            return jax.jit(compat.shard_map(
-                body, mesh, in_specs=(P(), P(axis), P(axis), P(axis)),
+            return jax.jit(jax.shard_map(
+                body, mesh=mesh, in_specs=(P(), P(axis), P(axis), P(axis)),
                 out_specs=(P(), P()),
                 axis_names=frozenset(axes), check_vma=False))
 

@@ -8,7 +8,7 @@ whose residency fits the VMEM budget (``ops.delta_vmem_bytes`` — the SAME
 formula the runtime router uses, so the two can never disagree about what
 fits), minimize the roofline time bound
 
-    max(delta_flops / PEAK_FLOPS, delta_hbm_bytes / HBM_BW)
+    max(delta_flops / peak_flops, delta_hbm_bytes / hbm_bw)
 
 where ``delta_hbm_bytes`` counts the blocked kernel's refetch traffic —
 larger tiles mean fewer refetches, so the model pushes tiles as large as
@@ -36,7 +36,7 @@ import json
 import os
 import threading
 
-from repro.distributed.roofline import HBM_BW, PEAK_FLOPS, VqCell
+from repro.distributed.roofline import VqCell, device_peaks
 
 MODES = ("off", "cache", "search")
 DEFAULT_TILES = (128, 128)          # the pre-autotune hardcoded tiles
@@ -134,8 +134,9 @@ def model_time(cfg: TileConfig, batch: int, kappa: int, d: int,
     """Roofline time bound (s) for one fused delta dispatch at these tiles."""
     cell = VqCell(d=d, kappa=kappa, tau=1, bm=cfg.bm, bk=cfg.bk,
                   dtype_bytes=dtype_bytes)
-    return max(cell.delta_flops(batch) / PEAK_FLOPS,
-               cell.delta_hbm_bytes(batch) / HBM_BW)
+    peaks = device_peaks()
+    return max(cell.delta_flops(batch) / peaks.flops,
+               cell.delta_hbm_bytes(batch) / peaks.hbm_bw)
 
 
 def _rank(cands: list[TileConfig], batch: int, kappa: int, d: int,

@@ -85,6 +85,7 @@ def vq_assign_pallas(z: jax.Array, w: jax.Array, *, bm: int = 128,
     grid = (batch // bm, kappa // bk)
     assign, mind = pl.pallas_call(
         functools.partial(_assign_kernel, bk=bk, kappa_valid=kappa_valid),
+        name="vq_assign",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, d), lambda i, j: (i, 0)),
@@ -156,6 +157,7 @@ def vq_delta_pallas(z: jax.Array, w: jax.Array, *, bm: int = 128,
 
     counts, zsum, mind = pl.pallas_call(
         functools.partial(_delta_kernel, bm=bm, n_valid=n_valid),
+        name="vq_delta",
         grid=(batch // bm,),
         in_specs=[
             pl.BlockSpec((bm, d), lambda i: (i, 0)),

@@ -39,6 +39,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.vq_assign import BIG
 
@@ -175,6 +176,7 @@ def vq_delta_blocked_pallas(z: jax.Array, w: jax.Array, *, bm: int, bk: int,
         functools.partial(_fused_delta_kernel, bm=bm, bk=bk, kb=kb,
                           n_valid=n_valid, kappa_valid=kappa_valid,
                           with_delta=with_delta),
+        name="vq_delta_blocked",
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
@@ -234,12 +236,17 @@ def _window_kernel(z_ref, w0_ref, eps_ref, wout_ref, *, tau: int):
 
     z_ref:   (tau, d)    the window's point stream
     w0_ref:  (kappa, d)  prototypes entering the window
-    eps_ref: (tau, 1)    precomputed Robbins-Monro steps (f32)
+    eps_ref: (tau,)      precomputed Robbins-Monro steps (f32, in SMEM)
     wout_ref:(kappa, d)  prototypes after the window
 
-    Bitwise equality with the per-step scan is load-bearing (the engine CI
-    gate and the mesh-vs-oracle tier-1 pins both ride on it), and two
-    compilation artifacts can silently break it:
+    Each step reads its point and its step size from the refs at ``t``:
+    Mosaic lowers a dynamic ref index, not a ``dynamic_slice`` of a loaded
+    value.  The winning code stays a (1, 1) vector, never a scalar, so the
+    one-hot compare is a sublane broadcast.
+
+    Bitwise equality with the per-step scan is load-bearing on XLA:CPU (the
+    engine CI gate and the mesh-vs-oracle tier-1 pins both ride on it), and
+    two compilation artifacts can silently break it:
 
       * SHAPES: XLA's reduction/matmul emission is shape-dependent, so the
         distance ops here must see the SAME shapes as ``_delta_kernel``
@@ -258,21 +265,18 @@ def _window_kernel(z_ref, w0_ref, eps_ref, wout_ref, *, tau: int):
         jitted-scan equality that actually matters.
     """
     kappa = w0_ref.shape[0]
-    zwin = z_ref[...].astype(jnp.float32)            # (tau, d)
-    eps_all = eps_ref[...]                           # (tau, 1)
 
     def step(t, w):
-        z = jax.lax.dynamic_slice_in_dim(zwin, t, 1, 0)          # (1, d)
+        z = z_ref[pl.ds(t, 1), :].astype(jnp.float32)            # (1, d)
         z2 = jnp.sum(z * z, axis=1, keepdims=True)               # (1, 1)
         w2 = jnp.sum(w * w, axis=1)[None, :]
         d2 = z2 - 2.0 * (z @ w.T) + w2                           # (1, kappa)
-        arg = jnp.argmin(d2, axis=1)                             # (1,)
+        arg = jnp.argmin(d2, axis=1, keepdims=True)              # (1, 1)
         onehot = (jax.lax.broadcasted_iota(jnp.int32, (kappa, 1), 0)
-                  == arg[0]).astype(jnp.float32)                 # (kappa, 1)
+                  == arg).astype(jnp.float32)                    # (kappa, 1)
         zsum = onehot * z                                        # (kappa, d)
         h = onehot * w - zsum
-        eps = jax.lax.dynamic_slice_in_dim(eps_all, t, 1, 0)[0, 0]
-        return w - eps * h
+        return w - eps_ref[t] * h
 
     wout_ref[...] = jax.lax.fori_loop(
         0, tau, step, w0_ref[...].astype(jnp.float32))
@@ -285,14 +289,14 @@ def vq_window_pallas(zwin: jax.Array, w0: jax.Array, eps: jax.Array, *,
     kappa, _ = w0.shape
     return pl.pallas_call(
         functools.partial(_window_kernel, tau=tau),
+        name="vq_window",
         grid=(1,),
         in_specs=[
             pl.BlockSpec((tau, d), lambda i: (0, 0)),
             pl.BlockSpec((kappa, d), lambda i: (0, 0)),
-            pl.BlockSpec((tau, 1), lambda i: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((kappa, d), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((kappa, d), jnp.float32),
         interpret=interpret,
-    )(zwin, w0.astype(jnp.float32),
-      eps.reshape(tau, 1).astype(jnp.float32))
+    )(zwin, w0.astype(jnp.float32), eps.reshape(tau).astype(jnp.float32))

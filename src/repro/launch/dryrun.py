@@ -249,9 +249,10 @@ def run_cell(arch_id: str, shape_name: str, *, multi_pod: bool,
                 flops = 4.0 * dp * tau * kappa * d / n_dev
                 hbm = kappa * d * 4 * 3
             terms = {
-                "t_compute": flops / roofline.PEAK_FLOPS,
-                "t_memory": hbm / roofline.HBM_BW,
-                "t_collective": coll["total_bytes"] / roofline.ICI_BW,
+                "t_compute": flops / roofline.MODEL_PEAKS.flops,
+                "t_memory": hbm / roofline.MODEL_PEAKS.hbm_bw,
+                "t_collective": (coll["total_bytes"]
+                                 / roofline.MODEL_PEAKS.ici_bw),
             }
             terms["dominant"] = max(
                 ("compute", "memory", "collective"),
@@ -282,7 +283,8 @@ def run_cell(arch_id: str, shape_name: str, *, multi_pod: bool,
             coll["total_bytes"] / per_step_div)
         rec["per_step_divisor"] = per_step_div
         rec["t_collective_tpu_adjusted"] = (
-            coll["tpu_adjusted_bytes"] / per_step_div / roofline.ICI_BW)
+            coll["tpu_adjusted_bytes"] / per_step_div
+            / roofline.MODEL_PEAKS.ici_bw)
         rec.update({
             "status": "ok",
             "compile_s": round(time.perf_counter() - t0, 1),

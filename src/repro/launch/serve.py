@@ -26,6 +26,8 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro import compile_cache
+
 
 def run_vq(args) -> int:
     """Drive the quantization service: store -> service -> load -> report."""
@@ -49,8 +51,13 @@ def run_vq(args) -> int:
     kd, kw, ka = jax.random.split(key, 3)
     n_dev = len(jax.devices())
     m_train = min(8, n_dev)
-    data = synthetic.replicate_stream(kd, m_train, n=args.points, d=args.dim)
-    w0 = synthetic.kmeanspp_init(kw, data.reshape(-1, args.dim), args.kappa)
+    # the initial codebook samples kappa distinct points, so draw at least
+    # that many even when --points (the trainer's stream) is shorter
+    pool = synthetic.replicate_stream(
+        kd, m_train, n=max(args.points, -(-args.kappa // m_train)),
+        d=args.dim)
+    data = pool[:, :args.points]
+    w0 = synthetic.kmeanspp_init(kw, pool.reshape(-1, args.dim), args.kappa)
 
     net_kw = {}
     if args.network == "fixed":
@@ -234,6 +241,7 @@ def main(argv=None) -> int:
                          "histograms) as JSONL")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     if args.mode == "vq":
         return run_vq(args)

@@ -64,6 +64,7 @@ import time
 import jax
 import numpy as np
 
+from repro import compile_cache
 from repro.checkpoint.checkpointing import Checkpointer
 from repro.configs import registry
 from repro.data.pipeline import DataConfig, lm_batch
@@ -512,6 +513,7 @@ def main(argv=None) -> int:
                          "kind, so a cache never leaks across accelerators)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     from repro.kernels import autotune
     autotune.set_mode(args.autotune)

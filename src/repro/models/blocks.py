@@ -10,7 +10,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.models import common
 from repro.models.common import ModelConfig, rope
 
@@ -263,8 +262,8 @@ def moe_apply_ep(cfg: ModelConfig, p: dict, x: jax.Array,
                          * gates[..., None].astype(yu.dtype), axis=2)
         return jax.lax.psum(y_part.astype(jnp.float32), "model")
 
-    fn = compat.shard_map(
-        body, mesh,
+    fn = jax.shard_map(
+        body, mesh=mesh,
         in_specs=(P(), P(), P("model"), P("model"), P("model")),
         out_specs=P(),
         axis_names=frozenset({"model"}), check_vma=False)

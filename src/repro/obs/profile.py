@@ -7,7 +7,7 @@ per window against the three-term roofline
 
 * ``compute``    — analytic device FLOPs for the VQ inner loop
   (``VqCell.window_flops``, the (d, kappa, tau, bm) hand count) over the
-  TPU-v5e peak,
+  chip's peak (``roofline.device_peaks``: the v5e's off-TPU),
 * ``memory``     — analytic HBM traffic (``VqCell.window_hbm_bytes``)
   over HBM bandwidth,
 * ``collective`` — merge bytes parsed out of the *actual compiled*
@@ -46,7 +46,7 @@ import json
 from typing import Any
 
 from repro.distributed import hlo_analysis
-from repro.distributed.roofline import (HBM_BW, ICI_BW, PEAK_FLOPS, VqCell,
+from repro.distributed.roofline import (VqCell, device_peaks,
                                         vq_roofline_terms)
 
 TERMS = ("compute", "memory", "collective", "host")
@@ -77,6 +77,7 @@ class Profiler:
 
     def __init__(self, *, metrics=None):
         self.metrics = metrics
+        self.peaks = device_peaks()
         self.programs: dict[str, ProgramCost] = {}
         self.attributions: list[dict] = []
         self._pending: list[dict] = []
@@ -137,7 +138,8 @@ class Profiler:
             coll_per_win = (prog.collective_bytes / s["n_windows"]
                             if prog is not None else None)
             terms = vq_roofline_terms(
-                cell, collective_bytes_per_window=coll_per_win)
+                cell, collective_bytes_per_window=coll_per_win,
+                peaks=self.peaks)
             w = s["n_windows"] / total_windows
             for k in t:
                 t[k] += terms[f"t_{k}"] * w
@@ -171,7 +173,7 @@ class Profiler:
             "window_hbm_bytes": hbm,
             "collective_bytes_per_window": coll_bytes,
             "compiled_in_run": any(s["compiled"] for s in segs),
-            "peaks": {"flops": PEAK_FLOPS, "hbm_bw": HBM_BW, "ici_bw": ICI_BW},
+            "peaks": dataclasses.asdict(self.peaks),
         }
         self.attributions.append(rec)
         if self.metrics is not None:

@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.engine.mesh import make_worker_mesh
 from repro.kernels import ops
 
@@ -125,8 +124,8 @@ class ShardedLookup:
             def body(z_l, w_l):
                 return ops.vq_assign(z_l, w_l, bm=bm, bk=bk)
 
-            self._compiled[key] = jax.jit(compat.shard_map(
-                body, self.mesh, in_specs=(P(self.axis), P()),
+            self._compiled[key] = jax.jit(jax.shard_map(
+                body, mesh=self.mesh, in_specs=(P(self.axis), P()),
                 out_specs=(P(self.axis), P(self.axis)),
                 axis_names=frozenset({self.axis}), check_vma=False))
         return self._compiled[key](z, w)
@@ -145,7 +144,7 @@ class ShardedLookup:
             axis, bm, bk = self.axis, self.bm, self.bk
 
             def body(z_l, w_l):
-                a_l, m_l = ops.vq_assign(z_l[0], w_l, bm=bm, bk=bk)
+                a_l, m_l = ops.vq_assign(z_l, w_l, bm=bm, bk=bk)
                 gidx = a_l + jax.lax.axis_index(axis) * w_l.shape[0]
                 gmin = jax.lax.pmin(m_l, axis)
                 # among shards tied at the global min, the LOWEST global
@@ -155,14 +154,10 @@ class ShardedLookup:
                 garg = jax.lax.pmin(cand, axis)
                 return garg[None], gmin[None]
 
-            self._compiled[key] = jax.jit(compat.shard_map(
-                body, self.mesh,
-                in_specs=(P(self.axis), P(self.axis)),
+            self._compiled[key] = jax.jit(jax.shard_map(
+                body, mesh=self.mesh,
+                in_specs=(P(), P(self.axis)),
                 out_specs=(P(self.axis), P(self.axis)),
                 axis_names=frozenset({self.axis}), check_vma=False))
-        # replicate z by stacking one copy per shard: in_spec P(axis) hands
-        # each device its own full copy without relying on partial-manual
-        # replication (unsupported on the jax-0.4.x fallback toolchain)
-        zr = jnp.broadcast_to(z, (self.n_shards, *z.shape))
-        garg, gmin = self._compiled[key](zr, w)
+        garg, gmin = self._compiled[key](z, w)
         return garg[0], gmin[0]

@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import comm, compat
+from repro import comm
 from repro.models import blocks
 from repro.models.common import ModelConfig, rms_norm
 
@@ -160,8 +160,8 @@ def make_pp_loss_fn(cfg: ModelConfig, mesh: Mesh, *, n_micro: int
         if "lm_head" in params:
             pspec["lm_head"] = P()
             params["lm_head"] = params["lm_head"].astype(jnp.float32)
-        fn = compat.shard_map(
-            body, mesh,
+        fn = jax.shard_map(
+            body, mesh=mesh,
             in_specs=(pspec, {"tokens": P(), "labels": P()}),
             out_specs=P(),
             axis_names=frozenset({"pod"}), check_vma=False)

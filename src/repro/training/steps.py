@@ -31,7 +31,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro import comm
-from repro.compat import shard_map
 from repro.engine import merge as merge_lib
 from repro.models.api import get_api
 from repro.models.common import ModelConfig
@@ -178,7 +177,7 @@ def make_window_step(cfg: ModelConfig, optimizer: Optimizer, mesh,
         )
         out_specs = (jax.tree.map(lambda _: P(), state),
                      {"loss": P()})
-        fn = shard_map(
+        fn = jax.shard_map(
             window_body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
             axis_names=frozenset({axis}), check_vma=False)
         return fn(state, batches)

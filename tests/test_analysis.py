@@ -196,4 +196,29 @@ def test_vq_roofline_terms_default_to_analytic_merge_bytes():
     terms = roofline.vq_roofline_terms(cell)
     assert terms["collective_bytes"] == cell.merge_collective_bytes()
     assert terms["t_collective"] == pytest.approx(
-        cell.merge_collective_bytes() / roofline.ICI_BW)
+        cell.merge_collective_bytes() / roofline.MODEL_PEAKS.ici_bw)
+
+
+class _Dev:
+    def __init__(self, platform, device_kind):
+        self.platform, self.device_kind = platform, device_kind
+
+
+@pytest.mark.parametrize("platform,kind,want", [
+    ("cpu", "cpu", "model"),                 # off-TPU: the model's chip
+    ("tpu", "TPU v5 lite", "v5e"),           # a v5e: its published peaks
+    ("tpu", "TPU v9 imaginary", KeyError),   # unknown TPU: never a default
+])
+def test_device_peaks_keyed_by_device_kind(monkeypatch, platform, kind,
+                                           want):
+    import jax
+
+    monkeypatch.setattr(jax, "devices", lambda: [_Dev(platform, kind)])
+    if want is KeyError:
+        with pytest.raises(KeyError, match="no roofline peaks"):
+            roofline.device_peaks()
+        return
+    peaks = roofline.device_peaks()
+    assert peaks == roofline.PEAKS["TPU v5 lite"] == roofline.MODEL_PEAKS
+    assert (peaks.flops, peaks.hbm_bw, peaks.ici_bw) == (197e12, 819e9,
+                                                         200e9)

@@ -40,8 +40,8 @@ def _setup(m, n=400):
     return data, eval_data, w0
 
 
-def _run(scheme, m, transport, **kw):
-    data, eval_data, w0 = _setup(m)
+def _run(scheme, m, transport, n=400, **kw):
+    data, eval_data, w0 = _setup(m, n)
     ex = MeshExecutor(network=InstantNetwork(),
                       transport=transport, **kw)
     res = ex.run(scheme, w0, data, eval_data, tau=TAU,
@@ -140,6 +140,43 @@ def test_ring_matches_xla_exactly(scheme, m):
         assert merge["wire_bytes"] == 0
     else:
         assert merge["wire_bytes"] > 0
+
+
+@pytest.mark.devices(4)
+@pytest.mark.parametrize("shape", [(16, 8), (3, 700), (1024, 128)])
+def test_ring_kernel_matches_psum(shape):
+    """The Pallas ring kernel itself, under TPU interpret mode on 4 CPU
+    devices (simulated RDMAs and semaphores): padding to whole tiles and
+    the two ring phases give psum's sums up to summation order."""
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    mesh = Mesh(np.array(jax.devices()[:4]), ("workers",))
+    x = jax.random.normal(KEY, (4, *shape))
+
+    def both(x_l):
+        return (comm.ring_all_reduce(x_l[0], "workers", interpret=True)[None],
+                jax.lax.psum(x_l[0], "workers")[None])
+
+    ring, ref = jax.jit(jax.shard_map(
+        both, mesh=mesh, in_specs=P("workers"),
+        out_specs=(P("workers"), P("workers")), check_vma=False))(x)
+    np.testing.assert_allclose(np.asarray(ring), np.asarray(ref),
+                               rtol=1e-6, atol=1e-5)
+
+
+@pytest.mark.devices(4)
+def test_ring_kernel_transport_matches_xla():
+    """``RingTransport(use_pallas=True)`` through the mesh executor: the
+    interpreted kernel carries the merges and the eval means, agreeing
+    with the XLA transport and charging the same wire."""
+    base, ex_xla = _run("delta", 4, "xla", n=20)
+    ring, ex = _run("delta", 4, comm.RingTransport(use_pallas=True), n=20)
+    np.testing.assert_allclose(np.asarray(ring.distortion),
+                               np.asarray(base.distortion), rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(ring.w_shared),
+                               np.asarray(base.w_shared), atol=1e-5)
+    assert (ex.last_comm["by_tag"]["merge"]["wire_bytes"]
+            == ex_xla.last_comm["by_tag"]["merge"]["wire_bytes"])
 
 
 @pytest.mark.parametrize(

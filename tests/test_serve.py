@@ -474,6 +474,16 @@ def test_serve_cli_vq_smoke(capsys):
     assert "0 failed" in out and "plan=" in out
 
 
+def test_serve_cli_codebook_larger_than_training_stream(capsys):
+    """The initial codebook samples kappa points even where --points (the
+    trainer's per-worker stream) holds fewer than kappa in all."""
+    rc = serve_cli.main(["--mode", "vq", "--smoke", "--requests", "20",
+                         "--dim", "8", "--kappa", "2048", "--tick-ms", "0"])
+    out = capsys.readouterr().out
+    assert rc == 0
+    assert "0 failed" in out
+
+
 def test_serve_cli_train_publish_smoke(capsys):
     rc = serve_cli.main(["--mode", "vq", "--smoke", "--requests", "30",
                          "--dim", "8", "--kappa", "8", "--train-publish",
