@@ -1,0 +1,6 @@
+"""Share of the traced training window in which no operation ran on
+device 0."""
+
+
+def read(run):
+    return 100.0 * run.summary.idle_share(0)
