@@ -320,7 +320,8 @@ class ElasticMeshExecutor:
                 self.on_window(gw, w)
             if (periodic and gw % self.checkpoint_every == 0
                     and gw > self._last_ckpt_window):
-                with self.tracer.span("checkpoint", step=gw, periodic=True):
+                with self.tracer.span("elastic.checkpoint", step=gw,
+                                      periodic=True):
                     state = {"w_srd": jnp.asarray(jax.device_get(w)),
                              "t": np.asarray(_t + wi * tau, np.int64),
                              "cursor": np.asarray(_cur + wi * _m * tau,
@@ -374,7 +375,7 @@ class ElasticMeshExecutor:
             decay: float = 1.0, key: jax.Array | None = None) -> SchemeResult:
         del key  # sync schemes are deterministic; kept for Executor protocol
         t_wall = time.perf_counter()
-        with self.tracer.span("run", scheme=scheme, executor=self.name,
+        with self.tracer.span("elastic.run", scheme=scheme, executor=self.name,
                               m=data.shape[0] if data.ndim == 3 else None):
             res = self._run(scheme, w0, data, eval_data, tau=tau, eps0=eps0,
                             decay=decay)
@@ -461,8 +462,8 @@ class ElasticMeshExecutor:
             seg_w = min(max_w, want_w)
             if seg_w > 0:
                 seg_pts = cur_m * seg_w * tau
-                with self.tracer.span("resplit", m=cur_m, windows=seg_w,
-                                      points=seg_pts):
+                with self.tracer.span("elastic.resplit", m=cur_m,
+                                      windows=seg_w, points=seg_pts):
                     # reshard the global pool into cur_m time-major streams
                     seg = pool[cursor: cursor + seg_pts]
                     seg_data = seg.reshape(
@@ -538,7 +539,7 @@ class ElasticMeshExecutor:
         new_m, plan = self._clamp_m(ev.new_m)
         if cause == "chaos_kill" and self.metrics is not None:
             self.metrics.counter("chaos_kills").inc()
-        with self.tracer.span("resize", window=window_idx, old_m=cur_m,
+        with self.tracer.span("elastic.resize", window=window_idx, old_m=cur_m,
                               new_m=new_m, cause=cause):
             # un-commit the shared prototypes from the old mesh: the segment
             # output is sharded over the outgoing device set, and the next
@@ -554,7 +555,7 @@ class ElasticMeshExecutor:
                 n_dep = cur_m - new_m
                 need = n_dep * tau
                 if pool.shape[0] - cursor >= need:
-                    with self.tracer.span("late_delta", n_dep=n_dep,
+                    with self.tracer.span("elastic.late_delta", n_dep=n_dep,
                                           points=need):
                         d = pool.shape[-1]
                         late = pool[cursor: cursor + need].reshape(
@@ -590,14 +591,14 @@ class ElasticMeshExecutor:
                     if self.metrics is not None:
                         self.metrics.counter("late_delta_skipped").inc()
             # rebuild the mesh for the survivors (cached per M)
-            with self.tracer.span("remesh", m=new_m):
+            with self.tracer.span("elastic.remesh", m=new_m):
                 self._executor_for(new_m, cur_m)
                 jax.block_until_ready(w_srd)
             if self.checkpointer is not None:
                 # post-event state: a resume from here continues
                 # bit-identically (late deltas already integrated, cursor
                 # already advanced)
-                with self.tracer.span("checkpoint", step=window_idx):
+                with self.tracer.span("elastic.checkpoint", step=window_idx):
                     state = {"w_srd": w_srd,
                              "t": np.asarray(t0, np.int64),
                              "cursor": np.asarray(cursor, np.int64),

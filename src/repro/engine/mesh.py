@@ -60,6 +60,14 @@ from repro.topology import make_worker_mesh  # noqa: F401 — re-export; the
 # build meshes — CI-pinned)
 
 
+# Names of the device work inside a compiled segment: each scope lands in
+# its ops' op_name metadata, which a profiler trace shows as the op's
+# ``tf_op`` path, so the ops are found by name after any refactor.
+LOCAL_WINDOW_SCOPE = "local_window"
+MERGE_SCOPE = "merge"
+EVAL_PROBE_SCOPE = "eval_probe"
+
+
 def _validate_axis_names(mesh: Mesh, axes: tuple[str, ...]) -> None:
     if any(not name for name in mesh.axis_names):
         raise ValueError(
@@ -216,8 +224,8 @@ class MeshExecutor:
         # The async scheme has no window barrier: it publishes once, at end.
         self.on_window = on_window
         self.publish_every = publish_every
-        # observability: a disabled tracer is a constant-time no-op, so the
-        # hot path stays on the <3% overhead budget the obs bench enforces;
+        # observability: a disabled tracer records nothing (its wall spans
+        # still open profiler annotations) and compiles the bare program;
         # when a registry is attached every CommRecord is mirrored onto it
         # (per-tag/per-tier wire bytes become first-class metrics)
         self.tracer = tracer if tracer is not None else NULL_TRACER
@@ -271,7 +279,7 @@ class MeshExecutor:
         if cache_key not in self._compiled:
             fn = build()
             mark = log.mark()
-            with self.tracer.span("compile", program=str(cache_key[0])):
+            with self.tracer.span("engine.compile", program=str(cache_key[0])):
                 if self.profiler is not None:
                     # AOT split: .lower() runs the Python trace (appending
                     # the CommRecords exactly once), .compile() yields the
@@ -324,8 +332,9 @@ class MeshExecutor:
         mark = self.transport.log.mark()
         t_wall = time.perf_counter()
         try:
-            with self.tracer.span("run", scheme=scheme, executor=self.name,
-                                  m=m, transport=self.transport.name):
+            with self.tracer.span("engine.run", scheme=scheme,
+                                  executor=self.name, m=m,
+                                  transport=self.transport.name):
                 if scheme == "async_delta":
                     res = self._run_async(mesh, w0, data, eval_data, tau=tau,
                                           eps0=eps0, decay=decay, key=key)
@@ -377,7 +386,7 @@ class MeshExecutor:
         _validate_mesh(mesh, self._axes, m)
         mark = self.transport.log.mark()
         try:
-            with self.tracer.span("segment", scheme=scheme, m=m, t0=t0):
+            with self.tracer.span("engine.segment", scheme=scheme, m=m, t0=t0):
                 if (self.on_window is not None
                         or self.tier1_controller is not None):
                     res = self._run_sync_published(mesh, scheme, w0, data,
@@ -435,7 +444,7 @@ class MeshExecutor:
             k = min(self.publish_every, n_windows - done)
             seg = data[:, done * tau:(done + k) * tau]
             cmark = self.transport.log.mark()
-            with self.tracer.span("chunk", windows=k, t0=t):
+            with self.tracer.span("engine.chunk", windows=k, t0=t):
                 res, ms = self._run_sync(mesh, scheme, w, seg, eval_data,
                                          tau=tau, eps0=eps0, decay=decay,
                                          t0=t, merge_state=ms)
@@ -566,16 +575,15 @@ class MeshExecutor:
             def window(carry, x):
                 zwin = x[0]
                 w_srd, t, ms = carry
-                _, w_fin = _local_window(w_srd, zwin, t, eps0=eps0,
-                                         decay=decay, use_pallas=use_pallas,
-                                         vmem_budget=vmem_budget,
-                                         fused=fused)
-                if quorum:
+                with jax.named_scope(LOCAL_WINDOW_SCOPE):
+                    _, w_fin = _local_window(
+                        w_srd, zwin, t, eps0=eps0, decay=decay,
+                        use_pallas=use_pallas, vmem_budget=vmem_budget,
+                        fused=fused)
+                late = {"late": x[1]} if quorum else {}
+                with jax.named_scope(MERGE_SCOPE):
                     w_srd, ms = strategy(w_srd, w_fin, axis, ms,
-                                         calls=n_windows, late=x[1])
-                else:
-                    w_srd, ms = strategy(w_srd, w_fin, axis, ms,
-                                         calls=n_windows)
+                                         calls=n_windows, **late)
                 # the dynamic merge's per-window sync decision, stacked into
                 # a program output so the host can re-price the wire and tag
                 # the trace with what actually triggered
@@ -586,14 +594,16 @@ class MeshExecutor:
                     # observing program keeps the bare program's collective
                     # count, so live instrumentation stays on the <3% obs
                     # bench budget
-                    cd, _ = transport.all_reduce(
-                        jnp.stack([vq.distortion(ev, w_srd),
-                                   jnp.sum((w_fin - w_srd) ** 2)]),
-                        axis, op="mean", calls=n_windows, tag="eval")
+                    with jax.named_scope(EVAL_PROBE_SCOPE):
+                        cd, _ = transport.all_reduce(
+                            jnp.stack([vq.distortion(ev, w_srd),
+                                       jnp.sum((w_fin - w_srd) ** 2)]),
+                            axis, op="mean", calls=n_windows, tag="eval")
                     return (w_srd, t, ms), (cd[0], cd[1]) + extra
-                c, _ = transport.all_reduce(
-                    vq.distortion(ev, w_srd), axis, op="mean",
-                    calls=n_windows, tag="eval")
+                with jax.named_scope(EVAL_PROBE_SCOPE):
+                    c, _ = transport.all_reduce(
+                        vq.distortion(ev, w_srd), axis, op="mean",
+                        calls=n_windows, tag="eval")
                 return (w_srd, t, ms), ((c,) + extra if dynamic else c)
 
             (w_srd, _, ms_out), ys = jax.lax.scan(
@@ -873,10 +883,11 @@ class MeshExecutor:
                     cs0)
             carry, traj = jax.lax.scan(tick, init, stream)
             w_srd_final = carry[1]
-            sel = traj[eval_ticks]                   # (n_evals, kappa, d)
-            c_local = jax.vmap(lambda w_: vq.distortion(ev, w_))(sel)
-            curve, _ = transport.all_reduce(c_local, axis, op="mean",
-                                            tag="eval")
+            with jax.named_scope(EVAL_PROBE_SCOPE):
+                sel = traj[eval_ticks]               # (n_evals, kappa, d)
+                c_local = jax.vmap(lambda w_: vq.distortion(ev, w_))(sel)
+                curve, _ = transport.all_reduce(c_local, axis, op="mean",
+                                                tag="eval")
             return w_srd_final, curve
 
         cache_key = ("async", mesh, w0.shape, data.shape, eval_data.shape,

@@ -39,8 +39,8 @@ class SimExecutor:
             decay: float = 1.0, key: jax.Array | None = None) -> SchemeResult:
         api.validate_scheme(scheme)
         t_wall = time.perf_counter()
-        with self.tracer.span("run", scheme=scheme, executor=self.name,
-                              m=data.shape[0]):
+        with self.tracer.span("engine.run", scheme=scheme,
+                              executor=self.name, m=data.shape[0]):
             if scheme in ("average", "delta"):
                 fn = (schemes.scheme_average if scheme == "average"
                       else schemes.scheme_delta)

@@ -47,8 +47,8 @@ class ThreadExecutor:
                 f"MeshExecutor for the synchronous schemes")
         del eval_data, key  # the runtime evaluates on its own data slice
         t_wall = time.perf_counter()
-        with self.tracer.span("run", scheme=scheme, executor=self.name,
-                              m=data.shape[0]):
+        with self.tracer.span("engine.run", scheme=scheme,
+                              executor=self.name, m=data.shape[0]):
             w, stats, trace = async_runtime.run_async_vq(
                 np.asarray(data, np.float32), np.asarray(w0, np.float32),
                 tau=tau, duration_s=self.duration_s, eps0=eps0, decay=decay,

@@ -100,7 +100,7 @@ def run_vq(args) -> int:
     t0 = time.perf_counter()
     with QuantizeService(store, lookup,
                          max_delay_s=args.max_delay_ms * 1e-3,
-                         tracer=tracer, metrics=metrics) as service:
+                         tracer=tracer) as service:
         if trainer is not None:
             trainer.start()
             # don't let the load race the trainer's compile: wait for the
@@ -120,6 +120,15 @@ def run_vq(args) -> int:
 
     print(report.summary())
     st = service.stats
+    if metrics is not None:
+        # the service keeps its counters in ServiceStats; copy them in
+        # once, now that the flush thread has stopped
+        for kind in ("full", "deadline"):
+            metrics.counter("serve_flushes", kind=kind).inc(
+                getattr(st, f"{kind}_flushes"))
+        metrics.counter("serve_rows").inc(st.rows)
+        metrics.counter("serve_padded_rows").inc(st.padded_rows)
+        metrics.counter("serve_failed").inc(st.failed)
     print(f"flushes={st.flushes} (full={st.full_flushes} "
           f"deadline={st.deadline_flushes}) mean_fill={st.mean_fill:.1f} "
           f"rows/flush, padded_rows={st.padded_rows}")
@@ -237,8 +246,8 @@ def main(argv=None) -> int:
                     help="write a Chrome trace-event file (Perfetto): "
                          "flush spans, load spans, trainer windows")
     ap.add_argument("--metrics", default="", metavar="OUT.jsonl",
-                    help="append the metrics registry (latency/fill/queue "
-                         "histograms) as JSONL")
+                    help="append the metrics registry (latency histogram, "
+                         "flush/row counters) as JSONL")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
     compile_cache.enable()

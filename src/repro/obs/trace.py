@@ -2,25 +2,34 @@
 
 Two kinds of spans share one timeline:
 
-* **wall spans** — real host work (``with tracer.span("run", ...):``),
-  stamped with ``time.monotonic_ns`` (never ``time.time`` — span math must
-  not jump with wall-clock adjustments).  Nesting is the natural ``with``
-  nesting; a span records its attrs, track, and thread automatically.
+* **wall spans** — real host work (``with tracer.span("engine.run", ...):``).
+  Every wall span also opens a ``jax.profiler.TraceAnnotation`` of the
+  same name, whether or not the tracer records, so a ``jax.profiler``
+  session sees it on its host plane, stamped by the profiler's own clock:
+  the clock the device's op events are put on, so host spans and device
+  ops line up in one trace.  A recording tracer also keeps the span in its
+  buffer, stamped with ``time.monotonic_ns`` from the tracer's creation
+  (never ``time.time`` — span math must not jump with wall-clock
+  adjustments).  Nesting is the natural ``with`` nesting; a span records
+  its attrs, track, and thread automatically.  Names carry their layer
+  (``serve.*``, ``engine.*``, ``loadgen.*``, ``elastic.*``).
 * **modeled spans** — the engine's tick-timeline reconstruction
   (``tracer.add_span(...)`` with explicit start/duration).  The mesh
   engine runs windows as fused device scans, so per-worker compute and
   merge phases are *modeled* from the same ``NetworkModel`` arithmetic
   that produces ``wall_ticks`` — which is exactly what makes the eq.-9
   compute/communication overlap visible in Perfetto without
-  de-optimising the hot path.
+  de-optimising the hot path.  They are not wall time, and never reach
+  the profiler.
 
 Counters (``tracer.counter``) become Chrome ``"C"`` events — Perfetto
 renders them as per-process line charts (distortion and codebook
 divergence over the run).
 
-``Tracer(enabled=False)`` (or the shared ``NULL_TRACER``) makes every
-call a constant-time no-op so instrumented code paths stay on the
-<3% overhead budget the obs bench gate enforces.
+``Tracer(enabled=False)`` (or the shared ``NULL_TRACER``) records nothing:
+``add_span`` and ``counter`` return at once, and ``span`` only opens the
+profiler annotation, which costs one check of whether a profiler session
+is recording while none is.
 
 The exported file is plain Chrome trace-event JSON: open it at
 https://ui.perfetto.dev (or chrome://tracing).  ``ts``/``dur`` are
@@ -66,6 +75,8 @@ class CounterEvent:
 class Tracer:
     """Bounded span/counter recorder; thread-safe; monotonic-clock.
 
+    ``enabled`` governs the buffer and the Chrome export only: wall spans
+    go to the profiler either way (module docstring).
     ``process``/``track`` name the Perfetto lanes.  Wall spans default to
     ``process="host"`` and the current thread's name; modeled spans pick
     their own (e.g. ``process="ticks", track="worker 3"``).
@@ -133,26 +144,31 @@ class Tracer:
     @contextlib.contextmanager
     def span(self, name: str, *, process: str | None = None,
              track: str | None = None, **attrs):
-        """Record a real (monotonic-clock) span around the ``with`` body."""
-        if not self.enabled:
-            yield None
-            return
-        ev = SpanEvent(
-            name=name, start_us=self.now_us(), dur_us=None,
-            process=process or self.WALL_PROCESS,
-            track=track or threading.current_thread().name,
-            attrs=attrs)
-        with self._lock:
-            self._spans.append(ev)
-            self._open += 1
-            if len(self._spans) > self.max_spans:
-                self._trim()
-        try:
-            yield ev
-        finally:
-            ev.dur_us = self.now_us() - ev.start_us
+        """A wall span around the ``with`` body: a profiler annotation
+        named ``name`` always, and a buffered (monotonic-clock) span when
+        the tracer records."""
+        from jax.profiler import TraceAnnotation
+
+        with TraceAnnotation(name):
+            if not self.enabled:
+                yield None
+                return
+            ev = SpanEvent(
+                name=name, start_us=self.now_us(), dur_us=None,
+                process=process or self.WALL_PROCESS,
+                track=track or threading.current_thread().name,
+                attrs=attrs)
             with self._lock:
-                self._open -= 1
+                self._spans.append(ev)
+                self._open += 1
+                if len(self._spans) > self.max_spans:
+                    self._trim()
+            try:
+                yield ev
+            finally:
+                ev.dur_us = self.now_us() - ev.start_us
+                with self._lock:
+                    self._open -= 1
 
     # -- modeled spans and counters ------------------------------------------
 
@@ -162,7 +178,7 @@ class Tracer:
 
         Lock-free: ``list.append`` is atomic under the GIL, and modeled
         spans are the instrumentation hot path (hundreds per window-scan
-        segment) — this call is on the obs bench's <3% overhead budget.
+        segment).  Modeled time is not wall time: no profiler annotation.
         """
         if not self.enabled:
             return
@@ -171,7 +187,7 @@ class Tracer:
             process or self.TICK_PROCESS, track, attrs))
         # bound check stays off the common path: with the default 1M cap
         # the branch is a len() compare, and only over-cap calls take the
-        # lock to trim — the obs bench's <3% overhead budget holds
+        # lock to trim
         if len(self._spans) > self.max_spans:
             with self._lock:
                 self._trim()

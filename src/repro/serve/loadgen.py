@@ -101,9 +101,9 @@ def run_load(service: QuantizeService, *, n_requests: int, d: int,
         return cb
 
     t0 = time.monotonic()
-    with tracer.span("load", requests=n_requests,
+    with tracer.span("loadgen.load", requests=n_requests,
                      rows_per_request=rows_per_request):
-        with tracer.span("submit"):
+        with tracer.span("loadgen.submit"):
             next_t = t0
             for i in range(n_requests):
                 next_t += gaps[i]
@@ -117,7 +117,7 @@ def run_load(service: QuantizeService, *, n_requests: int, d: int,
 
         failed = 0
         responses = []
-        with tracer.span("collect"):
+        with tracer.span("loadgen.collect"):
             for fut in futures:
                 try:
                     responses.append(fut.result(timeout=timeout_s))

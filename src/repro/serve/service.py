@@ -28,7 +28,7 @@ from concurrent.futures import Future
 import jax
 import numpy as np
 
-from repro.obs import NULL_TRACER, MetricsRegistry, Tracer
+from repro.obs import NULL_TRACER, Tracer
 from repro.serve.codebook_store import CodebookStore
 from repro.serve.lookup import ShardedLookup
 
@@ -52,12 +52,14 @@ class QuantizeResponse:
     mindist: np.ndarray             # (rows,) float32 squared distances
     version: int                    # CodebookStore version served
     latency_s: float                # submit -> response (service-internal)
+    queued_s: float                 # submit -> taken into a flush batch
     batch_rows: int                 # real rows of the coalesced flush batch
 
 
 @dataclasses.dataclass
 class ServiceStats:
-    """Counters the flush loop maintains (read them after ``stop``)."""
+    """The service's counters, kept by the flush loop (read them after
+    ``stop``)."""
 
     requests: int = 0
     rows: int = 0
@@ -93,13 +95,17 @@ class QuantizeService:
                  ``start()`` — otherwise the FIRST flush pays the lookup
                  compile and every request queued behind it eats it as
                  latency.
+    tracer:      wall spans of the flush thread (``serve.idle_wait``,
+                 ``serve.batch_wait``, ``serve.flush`` and its children
+                 ``serve.gather``, ``serve.lookup``, ``serve.sync``,
+                 ``serve.respond``); they reach a ``jax.profiler`` trace
+                 whether or not the tracer records.
     """
 
     def __init__(self, store: CodebookStore, lookup: ShardedLookup | None = None,
                  *, max_batch: int | None = None, max_delay_s: float = 2e-3,
                  batch_align: int = 128, warmup: bool = True,
-                 tracer: Tracer | None = None,
-                 metrics: MetricsRegistry | None = None):
+                 tracer: Tracer | None = None):
         self.store = store
         self.lookup = lookup if lookup is not None else ShardedLookup()
         if batch_align < 1:
@@ -118,10 +124,7 @@ class QuantizeService:
             raise ValueError(f"max_delay_s must be >= 0, got {max_delay_s}")
         self.max_delay_s = max_delay_s
         self.warmup = warmup
-        # flush spans ride the tracer's wall timeline on the flush thread's
-        # own track; fill/queue-depth land on the registry per flush
         self.tracer = tracer if tracer is not None else NULL_TRACER
-        self.metrics = metrics
         self.stats = ServiceStats()
         self._cond = threading.Condition()
         self._queue: list[QuantizeRequest] = []
@@ -193,8 +196,11 @@ class QuantizeService:
 
     # -- flush loop ---------------------------------------------------------
 
-    def _take_batch_locked(self) -> tuple[list[QuantizeRequest], bool]:
-        """Pop requests up to ``max_batch`` rows (always at least one)."""
+    def _take_batch_locked(self) -> tuple[list[QuantizeRequest], bool,
+                                          float]:
+        """Pop requests up to ``max_batch`` rows (always at least one);
+        returns them, whether they fill a batch, and when they were taken
+        (``time.monotonic``)."""
         take: list[QuantizeRequest] = [self._queue[0]]
         rows = take[0].rows
         while (len(take) < len(self._queue)
@@ -203,28 +209,31 @@ class QuantizeService:
             take.append(self._queue[len(take)])
         del self._queue[:len(take)]
         self._pending_rows -= rows
-        return take, rows >= self.max_batch
+        return take, rows >= self.max_batch, time.monotonic()
 
     def _flush_loop(self) -> None:
+        span = self.tracer.span
         while True:
             with self._cond:
-                while not self._queue and self._running:
-                    self._cond.wait()
+                with span("serve.idle_wait"):
+                    while not self._queue and self._running:
+                        self._cond.wait()
                 if not self._queue:
                     return  # stopped and drained
                 deadline = self._queue[0].submitted_at + self.max_delay_s
-                while (self._running
-                       and self._pending_rows < self.max_batch):
-                    left = deadline - time.monotonic()
-                    if left <= 0:
-                        break
-                    self._cond.wait(left)
+                with span("serve.batch_wait"):
+                    while (self._running
+                           and self._pending_rows < self.max_batch):
+                        left = deadline - time.monotonic()
+                        if left <= 0:
+                            break
+                        self._cond.wait(left)
                 depth = self._pending_rows      # queue depth at flush time
-                batch, full = self._take_batch_locked()
-            self._execute(batch, full, depth)
+                batch, full, taken_at = self._take_batch_locked()
+            self._execute(batch, full, taken_at, depth)
 
     def _execute(self, batch: list[QuantizeRequest], full: bool,
-                 depth: int = 0) -> None:
+                 taken_at: float, depth: int) -> None:
         # claim every future first: a client may have cancel()ed while the
         # request was queued, and resolving a cancelled future would raise
         # InvalidStateError and kill the flush thread; once claimed
@@ -233,48 +242,40 @@ class QuantizeService:
         if not batch:
             return
         rows = sum(r.rows for r in batch)
-        t_flush = time.perf_counter()
-        try:
-            with self.tracer.span("flush", rows=rows,
-                                  requests=len(batch), full=full,
-                                  queue_depth=depth):
-                snap = self.store.latest()
-                z = (batch[0].z if len(batch) == 1
-                     else np.concatenate([r.z for r in batch]))
-                pad = (-z.shape[0]) % self.batch_align
-                if pad:
-                    z = np.concatenate([z, np.zeros((pad, z.shape[1]),
-                                                    np.float32)])
-                assign, mind = self.lookup.assign(z, snap.w)
-                assign = np.asarray(assign)
-                mind = np.asarray(mind)
-        except Exception as e:  # noqa: BLE001 — fault goes to the callers
-            for r in batch:
-                r.future.set_exception(e)
-            self.stats.failed += len(batch)
-            if self.metrics is not None:
-                self.metrics.counter("serve_failed").inc(len(batch))
-            return
-        if self.metrics is not None:
-            mt = self.metrics
-            mt.histogram("serve_flush_wall_s").observe(
-                time.perf_counter() - t_flush)
-            mt.histogram("serve_batch_fill").observe(rows / self.max_batch)
-            mt.gauge("serve_queue_depth").set(depth)
-            mt.counter("serve_flushes",
-                       kind="full" if full else "deadline").inc()
-            mt.counter("serve_rows").inc(rows)
-            mt.counter("serve_padded_rows").inc(pad)
-        now = time.monotonic()
-        off = 0
-        for r in batch:
-            r.future.set_result(QuantizeResponse(
-                assign=assign[off:off + r.rows],
-                mindist=mind[off:off + r.rows],
-                version=snap.version,
-                latency_s=now - r.submitted_at,
-                batch_rows=rows))
-            off += r.rows
+        span = self.tracer.span
+        with span("serve.flush", rows=rows, requests=len(batch), full=full,
+                  queue_depth=depth):
+            try:
+                with span("serve.gather"):
+                    snap = self.store.latest()
+                    z = (batch[0].z if len(batch) == 1
+                         else np.concatenate([r.z for r in batch]))
+                    pad = (-z.shape[0]) % self.batch_align
+                    if pad:
+                        z = np.concatenate([z, np.zeros((pad, z.shape[1]),
+                                                        np.float32)])
+                with span("serve.lookup"):
+                    assign, mind = self.lookup.assign(z, snap.w)
+                with span("serve.sync"):
+                    assign = np.asarray(assign)
+                    mind = np.asarray(mind)
+            except Exception as e:  # noqa: BLE001 — fault goes to the callers
+                for r in batch:
+                    r.future.set_exception(e)
+                self.stats.failed += len(batch)
+                return
+            with span("serve.respond"):
+                now = time.monotonic()
+                off = 0
+                for r in batch:
+                    r.future.set_result(QuantizeResponse(
+                        assign=assign[off:off + r.rows],
+                        mindist=mind[off:off + r.rows],
+                        version=snap.version,
+                        latency_s=now - r.submitted_at,
+                        queued_s=taken_at - r.submitted_at,
+                        batch_rows=rows))
+                    off += r.rows
         self.stats.requests += len(batch)
         self.stats.rows += rows
         self.stats.flushes += 1

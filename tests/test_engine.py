@@ -6,6 +6,8 @@ the single-device oracles in ``core.schemes`` / ``core.async_vq`` to
 tolerance, on a 1-device mesh and on the full 8-way mesh.
 """
 
+import re
+
 from repro.xla_flags import force_host_devices
 
 # Flag must be set before jax initializes (the keras distribution_lib_test
@@ -23,6 +25,7 @@ from repro import engine  # noqa: E402
 from repro.engine import (GeometricDelayNetwork, InstantNetwork,  # noqa: E402
                           MeshExecutor, SimExecutor, ThreadExecutor,
                           get_executor, get_network, make_worker_mesh)
+from repro.engine import mesh as mesh_lib  # noqa: E402
 
 KEY = jax.random.PRNGKey(42)
 TAU = 10
@@ -89,6 +92,36 @@ def test_mesh_async_matches_oracle_with_shared_delays():
 
 
 @pytest.mark.devices(4)
+@pytest.mark.parametrize("scheme, scopes", [
+    ("delta", (mesh_lib.LOCAL_WINDOW_SCOPE, mesh_lib.MERGE_SCOPE,
+               mesh_lib.EVAL_PROBE_SCOPE)),
+    ("async_delta", (mesh_lib.EVAL_PROBE_SCOPE,)),
+])
+def test_compiled_segment_names_its_device_work(scheme, scopes):
+    """The program's named scopes reach the compiled HLO's op_name
+    metadata, which a profiler trace shows as each device op's ``tf_op``
+    path: the eval probe, the local window and the merge are found by
+    name, not by the fusion names XLA happens to give them."""
+    data, eval_data, w0 = _setup(1)
+    net = (InstantNetwork() if scheme == "delta"
+           else GeometricDelayNetwork(p_delay=0.5))
+    ex = MeshExecutor(network=net)
+    programs = []
+    run_compiled = ex._call_compiled
+
+    def spy(cache_key, build, *args):
+        programs.append((build(), args))
+        return run_compiled(cache_key, build, *args)
+
+    ex._call_compiled = spy
+    ex.run(scheme, w0, data, eval_data, tau=TAU)
+    (fn, args), = programs
+    text = fn.lower(*args).compile().as_text()
+    paths = [p.split("/") for p in re.findall(r'op_name="([^"]*)"', text)]
+    for scope in scopes:
+        assert any(scope in p for p in paths), scope
+
+
 def test_mesh_pallas_and_reference_inner_loops_agree():
     data, eval_data, w0 = _setup(4)
     a = MeshExecutor(network=InstantNetwork(), use_pallas=True).run(

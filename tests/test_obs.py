@@ -79,6 +79,44 @@ def test_null_tracer_is_inert():
     assert NULL_TRACER.spans() == [] and NULL_TRACER.counters() == []
 
 
+def test_wall_spans_open_a_profiler_annotation_recording_or_not(monkeypatch):
+    """Every wall span opens a ``jax.profiler.TraceAnnotation`` of its
+    name; a disabled tracer still opens it and records nothing, and
+    modeled spans never reach the profiler."""
+    opened = []
+
+    class Annotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            opened.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            opened.append(("exit", self.name))
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    off = Tracer(enabled=False)
+    with off.span("serve.flush", rows=3) as ev:
+        with off.span("serve.lookup"):
+            pass
+    assert ev is None
+    with pytest.raises(ValueError), off.span("serve.sync"):
+        raise ValueError("closed on the way out")
+    assert opened == [("enter", "serve.flush"), ("enter", "serve.lookup"),
+                      ("exit", "serve.lookup"), ("exit", "serve.flush"),
+                      ("enter", "serve.sync"), ("exit", "serve.sync")]
+    assert off.spans() == [] and off.open_spans == 0
+
+    opened.clear()
+    on = Tracer()
+    with on.span("engine.segment"):
+        assert on.open_spans == 1
+    on.add_span("compute", 0.0, 1.0, track="worker 0")
+    assert opened == [("enter", "engine.segment"), ("exit", "engine.segment")]
+    assert [e.name for e in on.spans()] == ["engine.segment", "compute"]
+
+
 def test_wall_spans_use_thread_name_as_track():
     tr = Tracer()
 

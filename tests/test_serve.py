@@ -218,6 +218,83 @@ def test_service_full_batch_flushes_before_deadline():
     assert svc.stats.mean_fill >= 16
 
 
+def test_service_stamps_queue_wait_within_latency():
+    """``queued_s`` (submit to taken into a flush) lies in [0, latency_s]
+    for every response; requests that fill a batch are taken before the
+    deadline, and a lone request waits it out."""
+    store = CodebookStore(_codebook())
+    filling = [_queries(16, fold=i) for i in range(4)]
+    lone = _queries(3, fold=50)
+    with QuantizeService(store, ShardedLookup(n_devices=1), max_batch=64,
+                         max_delay_s=0.5) as svc:
+        futs = [svc.submit(z) for z in filling]
+        resps = [f.result(timeout=10) for f in futs]
+        resps.append(svc.quantize(lone))
+    for r in resps:
+        assert 0.0 <= r.queued_s <= r.latency_s
+    assert all(r.queued_s < 0.5 for r in resps[:4])
+    assert resps[-1].queued_s >= 0.5
+    assert svc.stats.full_flushes == 1 and svc.stats.deadline_flushes == 1
+
+
+FLUSH_CHILDREN = ("serve.gather", "serve.lookup", "serve.sync",
+                  "serve.respond")
+
+
+def test_flush_spans_reach_the_profiler_host_plane(tmp_path):
+    """With no tracer given, the flush thread's spans still land on a
+    ``jax.profiler`` trace's host plane, each child inside its flush."""
+    from jax.profiler import ProfileData
+
+    store = CodebookStore(_codebook())
+    svc = QuantizeService(store, ShardedLookup(n_devices=1),
+                          max_delay_s=1e-3).start()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for k in range(3):
+            svc.quantize(_queries(2, fold=k))
+        svc.stop()
+    finally:
+        jax.profiler.stop_trace()
+    path, = tmp_path.rglob("*.xplane.pb")
+    spans = [(e.start_ns, e.start_ns + e.duration_ns, e.name)
+             for plane in ProfileData.from_file(str(path)).planes
+             if plane.name == "/host:CPU"
+             for line in plane.lines for e in line.events
+             if e.name.startswith("serve.")]
+    names = {n for _, _, n in spans}
+    assert {"serve.flush", "serve.batch_wait", "serve.idle_wait",
+            *FLUSH_CHILDREN} <= names
+    flushes = [(s, e) for s, e, n in spans if n == "serve.flush"]
+    assert len(flushes) == 3
+    for s, e, n in spans:
+        if n in FLUSH_CHILDREN:
+            assert any(fs <= s and e <= fe for fs, fe in flushes), n
+
+
+def test_program_span_names_carry_their_layer(monkeypatch):
+    """Every wall span the program opens is named ``<layer>.<what>``, and
+    none is one of the benchmark's own span names, which mark the edges of
+    a traced window."""
+    import importlib.util
+    import re
+
+    root = pathlib.Path(__file__).resolve().parent.parent
+    spec = importlib.util.spec_from_file_location(
+        "bench_xplane", root / "bench" / "xplane.py")
+    xplane = importlib.util.module_from_spec(spec)
+    monkeypatch.setitem(sys.modules, spec.name, xplane)
+    spec.loader.exec_module(xplane)
+    names = set()
+    for path in (root / "src" / "repro").rglob("*.py"):
+        names |= set(re.findall(r'\bspan\(\s*"([^"]+)"', path.read_text()))
+    assert {"serve.flush", "engine.segment", "engine.compile",
+            "loadgen.submit", "elastic.resize"} <= names
+    layers = ("serve.", "engine.", "loadgen.", "elastic.")
+    assert all(n.startswith(layers) for n in names), sorted(names)
+    assert not names & set(xplane.HARNESS_SPANS)
+
+
 def test_service_pads_to_mxu_alignment():
     store = CodebookStore(_codebook())
     svc = QuantizeService(store, ShardedLookup(n_devices=1),
