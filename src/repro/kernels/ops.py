@@ -71,9 +71,12 @@ def delta_fits_vmem(kappa: int, d: int, *, bm: int = 128,
 
 def window_vmem_bytes(kappa: int, d: int, tau: int, *,
                       dtype_bytes: int = 4) -> int:
-    """Residency of the fused window kernel: the (tau, d) point stream plus
-    its hoisted norms/steps, and ~4 (kappa, d)-sized codebook terms (w, wout,
-    zsum/h intermediates) with the one-hot/distance columns."""
+    """The router's conservative bound on the fused window kernel's
+    residency: the (tau, d) point stream plus its norms/steps, 4 (kappa,
+    d)-sized terms and 2 kappa-sized columns.  The kernel holds less: it
+    updates one row a step and forms no (kappa, d) one-hot, ``zsum`` or
+    ``h``.  A tighter count would move shapes (kappa=4,096 at d=128) from
+    the per-step route to the window route."""
     return dtype_bytes * (tau * (d + 2) + 4 * kappa * d + 2 * kappa)
 
 

@@ -23,11 +23,12 @@ Two fusions on top of the ``vq_assign.py`` pair:
     eq.-1 steps (batch of one point each) fused into one dispatch with the
     codebook resident in VMEM for the whole window.  Each step runs the
     same float ops as the per-step path (d2 via MXU contraction, strict
-    argmin, ``w - eps*(counts*w - zsum)``) on single-row operands, so the
-    fused window is bit-identical to the per-step scan it replaces — every
-    per-row reduction and product is independent of the seven padding rows
-    the unfused kernel carries.  That bit-stability is gated by the engine
-    benchmark's fused-vs-unfused records.
+    argmin, ``w - eps*(counts*w - zsum)``) on single-row operands, but
+    only on the winning row: the losing rows' update is the identity, so
+    the fused window is bit-identical to the per-step scan it replaces —
+    every per-row reduction and product is independent of the seven
+    padding rows the unfused kernel carries.  That bit-stability is gated
+    by the engine benchmark's fused-vs-unfused records.
 
 Block sizes come from ``kernels.autotune``; shapes are padded by ``ops.py``.
 """
@@ -231,18 +232,23 @@ def vq_topk_pallas(full: jax.Array, k: int, *, interpret: bool = False):
     return vals[0], idx[0], res
 
 
-def _window_kernel(z_ref, w0_ref, eps_ref, wout_ref, *, tau: int):
+def _window_kernel(z_ref, w0_ref, eps_ref, wout_ref, w2_ref, *, tau: int):
     """One fused window: tau sequential eq.-1 steps, codebook VMEM-resident.
 
     z_ref:   (tau, d)    the window's point stream
     w0_ref:  (kappa, d)  prototypes entering the window
     eps_ref: (tau,)      precomputed Robbins-Monro steps (f32, in SMEM)
-    wout_ref:(kappa, d)  prototypes after the window
+    wout_ref:(kappa, d)  prototypes after the window; the resident codebook
+    w2_ref:  (1, kappa)  VMEM scratch: the resident rows' squared norms
 
-    Each step reads its point and its step size from the refs at ``t``:
-    Mosaic lowers a dynamic ref index, not a ``dynamic_slice`` of a loaded
-    value.  The winning code stays a (1, 1) vector, never a scalar, so the
-    one-hot compare is a sublane broadcast.
+    The codebook is copied into ``wout_ref`` and its row norms into
+    ``w2_ref`` once, at window entry.  A step then touches one row: it
+    scores its point against the resident codebook with the kept norms,
+    takes the winning code as a scalar, loads that row, updates it, stores
+    it back, and refreshes that row's norm with a lane select.  Nothing of
+    (kappa, d) size is formed per step beyond the distance product.  The
+    step reads its point and step size from the refs at ``t``: Mosaic
+    lowers a dynamic ref index, not a ``dynamic_slice`` of a loaded value.
 
     Bitwise equality with the per-step scan is load-bearing on XLA:CPU (the
     engine CI gate and the mesh-vs-oracle tier-1 pins both ride on it), and
@@ -263,23 +269,34 @@ def _window_kernel(z_ref, w0_ref, eps_ref, wout_ref, *, tau: int):
         product to round first): eagerly-executed one-step programs round
         differently from either loop, and matching those breaks the
         jitted-scan equality that actually matters.
+
+    Updating only the winner is the same arithmetic as the per-step
+    path's full ``w - eps*(counts*w - zsum)``: on a losing row that
+    returns ``w`` unchanged, on the winning row it is ``w - eps*(w - z)``.
     """
     kappa = w0_ref.shape[0]
+    w0 = w0_ref[...].astype(jnp.float32)
+    wout_ref[...] = w0
+    w2_ref[...] = jnp.sum(w0 * w0, axis=1)[None, :]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, kappa), 1)
 
-    def step(t, w):
+    def step(t, carry):
         z = z_ref[pl.ds(t, 1), :].astype(jnp.float32)            # (1, d)
         z2 = jnp.sum(z * z, axis=1, keepdims=True)               # (1, 1)
-        w2 = jnp.sum(w * w, axis=1)[None, :]
-        d2 = z2 - 2.0 * (z @ w.T) + w2                           # (1, kappa)
+        w = wout_ref[...]
+        d2 = z2 - 2.0 * (z @ w.T) + w2_ref[...]                  # (1, kappa)
         arg = jnp.argmin(d2, axis=1, keepdims=True)              # (1, 1)
-        onehot = (jax.lax.broadcasted_iota(jnp.int32, (kappa, 1), 0)
-                  == arg).astype(jnp.float32)                    # (kappa, 1)
-        zsum = onehot * z                                        # (kappa, d)
-        h = onehot * w - zsum
-        return w - eps_ref[t] * h
+        k = arg[0, 0]
+        row = wout_ref[pl.ds(k, 1), :]                           # (1, d)
+        h = row - z
+        row = row - eps_ref[t] * h
+        wout_ref[pl.ds(k, 1), :] = row
+        w2_ref[...] = jnp.where(lane == arg,
+                                jnp.sum(row * row, axis=1, keepdims=True),
+                                w2_ref[...])
+        return carry
 
-    wout_ref[...] = jax.lax.fori_loop(
-        0, tau, step, w0_ref[...].astype(jnp.float32))
+    jax.lax.fori_loop(0, tau, step, 0)
 
 
 def vq_window_pallas(zwin: jax.Array, w0: jax.Array, eps: jax.Array, *,
@@ -298,5 +315,6 @@ def vq_window_pallas(zwin: jax.Array, w0: jax.Array, eps: jax.Array, *,
         ],
         out_specs=pl.BlockSpec((kappa, d), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((kappa, d), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((1, kappa), jnp.float32)],
         interpret=interpret,
     )(zwin, w0.astype(jnp.float32), eps.reshape(tau).astype(jnp.float32))

@@ -79,11 +79,32 @@ def test_vq_assign_autotuned_matches_ref(batch, kappa, d):
                                rtol=1e-4, atol=1e-4)
 
 
-def test_window_kernel_bitwise_matches_per_step_scan():
-    tau, kappa, d = 12, 16, 8
+def _window_case(case):
+    """(zwin, w0) for one window-kernel parity case.
+
+    ``normal``: the original small case.  ``uniform``: the training cells'
+    width on [0, 1] data.  ``repeat``: four codes for 64 points, so the
+    same rows win again and again and their kept norms are read after
+    their own update.  ``tie``: every codebook row twice, so each step's
+    argmin is a tie the first index must win."""
     kz, kw = jax.random.split(jax.random.fold_in(KEY, 99))
-    zwin = jax.random.normal(kz, (tau, d))
-    w0 = jax.random.normal(kw, (kappa, d))
+    if case == "normal":
+        return (jax.random.normal(kz, (12, 8)),
+                jax.random.normal(kw, (16, 8)))
+    if case == "uniform":
+        return (jax.random.uniform(kz, (10, 128)),
+                jax.random.uniform(kw, (1024, 128)))
+    if case == "repeat":
+        return (jax.random.normal(kz, (64, 8)),
+                jax.random.normal(kw, (4, 8)))
+    half = jax.random.normal(kw, (8, 8))
+    return jax.random.normal(kz, (10, 8)), jnp.concatenate([half, half])
+
+
+@pytest.mark.parametrize("case", ["normal", "uniform", "repeat", "tie"])
+def test_window_kernel_bitwise_matches_per_step_scan(case):
+    zwin, w0 = _window_case(case)
+    tau = zwin.shape[0]
     eps = vq.default_steps(1 + jnp.arange(tau, dtype=jnp.int32))
     w_fused = ops.vq_window(zwin, w0, eps)
 

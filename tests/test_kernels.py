@@ -105,6 +105,15 @@ def test_vmem_budget_routing():
             > ops.delta_vmem_bytes(128, 64))
 
 
+def test_window_route_at_the_training_cells_widths():
+    """The window router as the training cells see it at d=128, tau=10:
+    kappa=1,024 takes the fused window kernel, kappa=4,096 the per-step
+    route, under the default 8 MiB budget."""
+    assert ops.DEFAULT_VMEM_BUDGET_BYTES == 8 * 1024 * 1024
+    assert ops.window_fits_vmem(1024, 128, 10)
+    assert not ops.window_fits_vmem(4096, 128, 10)
+
+
 @pytest.mark.parametrize("batch,kappa,d", [(100, 200, 16), (64, 300, 8)])
 def test_vq_delta_routed_blocked_parity_kappa_gt_bk(batch, kappa, d):
     """kappa > bk forces the blocked-assign + segment-sum fallback; it must

@@ -54,7 +54,7 @@ def _f32(sharding, *shape):
     return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding)
 
 
-@pytest.mark.parametrize("d,kappa", [(128, 1024), (8, 16)])
+@pytest.mark.parametrize("d,kappa", [(128, 1024), (128, 2048), (8, 16)])
 def test_window_kernel_compiles(one_chip, d, kappa):
     tau = 10
     text = _compiled_text(
