@@ -81,6 +81,31 @@ def distortion_multi(z: jax.Array, w: jax.Array) -> jax.Array:
     return jnp.mean(jax.vmap(lambda zi: distortion(zi, w))(z))
 
 
+def pq_H(z: jax.Array, w: jax.Array) -> jax.Array:
+    """Eq. (4) in every sub-space of a product quantizer.
+
+    z: (d,), w: (m, k, d/m) sub-codebooks -> (m, k, d/m): in sub-space j,
+    ``w_j[l] - z_j`` on the row ``l`` nearest to the sub-vector ``z_j``,
+    zero elsewhere.  The sub-distances are exact float32 differences, not
+    the matmul expansion: at d/m = 8 the product would gain nothing and,
+    at the TPU's default one-bf16-pass precision, would flip argmins."""
+    m, k, ds = w.shape
+    h = w - z.reshape(m, 1, ds)
+    l = jnp.argmin(jnp.sum(h * h, axis=-1), axis=-1)  # (m,)
+    return jax.nn.one_hot(l, k, dtype=w.dtype)[..., None] * h
+
+
+def pq_distortion(z: jax.Array, w: jax.Array) -> jax.Array:
+    """Eq. (2) for a product quantizer: mean over the points ``z`` (n, d)
+    of the sum over sub-spaces of each sub-vector's squared distance to
+    its nearest code in ``w`` (m, k, d/m)."""
+    m, _, ds = w.shape
+    zs = jnp.swapaxes(z.reshape(-1, m, ds), 0, 1)  # (m, n, d/m)
+    mins = jax.vmap(lambda zj, wj: jnp.min(squared_distances(zj, wj),
+                                           axis=-1))(zs, w)
+    return jnp.mean(jnp.sum(mins, axis=0))
+
+
 def default_steps(t: jax.Array, *, eps0: float = 0.5, decay: float = 1.0) -> jax.Array:
     """The classical Robbins-Monro schedule eps_t = eps0 / (1 + decay * t).
 

@@ -94,8 +94,21 @@ def _local_window(w0: jax.Array, zwin: jax.Array, t0: jax.Array, *,
                   eps0: float, decay: float, use_pallas: bool,
                   vmem_budget: int | None = None, fused: bool = True
                   ) -> tuple[jax.Array, jax.Array]:
-    """tau sequential VQ steps (eq. 1) on one device; returns (delta, w)."""
+    """tau sequential VQ steps (eq. 1) on one device; returns (delta, w).
+
+    A product quantizer's (m, k, d/m) codebook takes each step in all m
+    sub-spaces at once: the ``pq_window`` kernel, or a scan of
+    ``vq.pq_H`` without Pallas."""
     tau = zwin.shape[0]
+    if w0.ndim == 3:
+        eps = vq.default_steps(t0 + 1 + jnp.arange(tau, dtype=jnp.int32),
+                               eps0=eps0, decay=decay)
+        if use_pallas:
+            w = ops.pq_window(zwin, w0, eps)
+        else:
+            w, _ = jax.lax.scan(lambda w, x: (w - x[1] * vq.pq_H(x[0], w),
+                                              None), w0, (zwin, eps))
+        return w0 - w, w
     kappa, d = w0.shape
     if (use_pallas and fused
             and ops.window_fits_vmem(kappa, d, tau,
@@ -315,6 +328,25 @@ class MeshExecutor:
 
     # -- public API ---------------------------------------------------------
 
+    def _check_codebook(self, scheme: str, w0, data) -> None:
+        """A (kappa, d) codebook runs every scheme and transport.  A product
+        quantizer's (m, k, d/m) sub-codebooks run the synchronous schemes
+        over the XLA transport, whose merges are elementwise."""
+        if w0.ndim == 2:
+            return
+        if w0.ndim != 3 or w0.shape[0] * w0.shape[2] != data.shape[-1]:
+            raise ValueError(
+                f"codebook must be (kappa, d) or (m, k, d/m) sub-codebooks "
+                f"of a product quantizer, got {w0.shape} for d="
+                f"{data.shape[-1]}")
+        if (scheme not in ("average", "delta")
+                or self.transport.name != "xla" or self.merge is not None):
+            raise ValueError(
+                f"a product quantizer trains with scheme 'average' or "
+                f"'delta' over the 'xla' transport and no merge override; "
+                f"got scheme {scheme!r}, transport "
+                f"{self.transport.name!r}, merge {self.merge!r}")
+
     def run(self, scheme: str, w0: jax.Array, data: jax.Array,
             eval_data: jax.Array, *, tau: int, eps0: float = 0.5,
             decay: float = 1.0, key: jax.Array | None = None) -> SchemeResult:
@@ -325,6 +357,7 @@ class MeshExecutor:
             raise ValueError(
                 f"eval_data must be (M, n_eval, d) with the same M as data; "
                 f"got {eval_data.shape} vs M={data.shape[0]}")
+        self._check_codebook(scheme, w0, data)
         m = data.shape[0]
         mesh = self.mesh if self.mesh is not None else make_worker_mesh(
             m, self.axis)
@@ -379,6 +412,7 @@ class MeshExecutor:
                 "to resize at")
         if data.ndim != 3:
             raise ValueError(f"data must be (M, n, d), got {data.shape}")
+        self._check_codebook(scheme, w0, data)
         m = data.shape[0]
         if mesh is None:
             mesh = self.mesh if self.mesh is not None else make_worker_mesh(
@@ -545,6 +579,7 @@ class MeshExecutor:
         use_pallas = self.use_pallas
         fused = self.fused
         vmem_budget = self.vmem_budget_bytes
+        probe = vq.pq_distortion if w0.ndim == 3 else vq.distortion
         if merge_state is None:
             # host-side merge state carries a leading per-worker dim: the
             # state (e.g. the sparse error-feedback residual) is DISTINCT
@@ -596,13 +631,13 @@ class MeshExecutor:
                     # bench budget
                     with jax.named_scope(EVAL_PROBE_SCOPE):
                         cd, _ = transport.all_reduce(
-                            jnp.stack([vq.distortion(ev, w_srd),
+                            jnp.stack([probe(ev, w_srd),
                                        jnp.sum((w_fin - w_srd) ** 2)]),
                             axis, op="mean", calls=n_windows, tag="eval")
                     return (w_srd, t, ms), (cd[0], cd[1]) + extra
                 with jax.named_scope(EVAL_PROBE_SCOPE):
                     c, _ = transport.all_reduce(
-                        vq.distortion(ev, w_srd), axis, op="mean",
+                        probe(ev, w_srd), axis, op="mean",
                         calls=n_windows, tag="eval")
                 return (w_srd, t, ms), ((c,) + extra if dynamic else c)
 
@@ -651,7 +686,9 @@ class MeshExecutor:
             self.profiler.note_segment(
                 program=cache_key, scheme=scheme,
                 transport=self.transport.name, topology=self._topology_label,
-                m=m, n_windows=n_windows, d=w0.shape[-1], kappa=w0.shape[0],
+                m=m, n_windows=n_windows, d=data.shape[-1],
+                kappa=w0.shape[-2],
+                subspaces=w0.shape[0] if w0.ndim == 3 else 1,
                 tau=tau, n_eval=eval_data.shape[1],
                 compiled=freshly_compiled)
         trig = None

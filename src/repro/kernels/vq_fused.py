@@ -30,6 +30,11 @@ Two fusions on top of the ``vq_assign.py`` pair:
     padding rows the unfused kernel carries.  That bit-stability is gated
     by the engine benchmark's fused-vs-unfused records.
 
+  * ``pq_window_pallas`` — the same window for a product quantizer: m
+    sub-codebooks of k codes over d/m-dimensional sub-vectors, all
+    resident, each step one nearest-code search and one one-row update in
+    every sub-space.
+
 Block sizes come from ``kernels.autotune``; shapes are padded by ``ops.py``.
 """
 
@@ -318,3 +323,58 @@ def vq_window_pallas(zwin: jax.Array, w0: jax.Array, eps: jax.Array, *,
         scratch_shapes=[pltpu.VMEM((1, kappa), jnp.float32)],
         interpret=interpret,
     )(zwin, w0.astype(jnp.float32), eps.reshape(tau).astype(jnp.float32))
+
+
+def _pq_window_kernel(z_ref, w0_ref, eps_ref, wout_ref, *, tau: int):
+    """One fused product-quantizer window: tau sequential eq.-1 steps in
+    each of the m sub-spaces at once, with the same step size in all.
+
+    z_ref:   (m, d/m, tau)  the window's points, one per lane column
+    w0_ref:  (m, d/m, k)    sub-codebooks entering the window
+    eps_ref: (tau,)         precomputed Robbins-Monro steps (f32, in SMEM)
+    wout_ref:(m, d/m, k)    sub-codebooks after the window; resident
+
+    Sub-codebook j is the (d/m, k) slab ``[j]``: its coordinates on
+    sublanes and its codes on lanes, so at d/m = 8 a sub-space is one
+    sublane tile and the search sums a tile's sublanes.  The sub-distances
+    are float32 differences, never a product.  A step broadcasts its
+    point's column along the lanes, takes the lowest-index nearest code of
+    every slab, and moves that one column of each slab by ``eps * (w -
+    z)``; every other column is left as it is.  The steps are unrolled, so
+    a point's column is a static lane slice.
+    """
+    m, _, k = w0_ref.shape
+    wout_ref[...] = w0_ref[...]
+    code = jax.lax.broadcasted_iota(jnp.int32, (m, 1, k), 2)
+    for t in range(tau):
+        z = z_ref[:, :, t:t + 1]                               # (m, ds, 1)
+        w = wout_ref[...]
+        h = w - z                                              # (m, ds, k)
+        d2 = jnp.sum(h * h, axis=1, keepdims=True)             # (m, 1, k)
+        best = jnp.min(d2, axis=2, keepdims=True)              # (m, 1, 1)
+        arg = jnp.min(jnp.where(d2 == best, code, k), axis=2, keepdims=True)
+        wout_ref[...] = jnp.where(code == arg, w - eps_ref[t] * h, w)
+
+
+def pq_window_pallas(zwin: jax.Array, w0: jax.Array, eps: jax.Array, *,
+                     interpret: bool = False) -> jax.Array:
+    """(tau, d), (m, k, d/m), (tau,) -> the sub-codebooks (m, k, d/m) after
+    tau fused sequential steps."""
+    tau, _ = zwin.shape
+    m, k, ds = w0.shape
+    zt = zwin.astype(jnp.float32).reshape(tau, m, ds).transpose(1, 2, 0)
+    wt = jnp.swapaxes(w0.astype(jnp.float32), 1, 2)
+    out = pl.pallas_call(
+        functools.partial(_pq_window_kernel, tau=tau),
+        name="pq_window",
+        grid=(1,),
+        in_specs=[
+            pl.BlockSpec((m, ds, tau), lambda i: (0, 0, 0)),
+            pl.BlockSpec((m, ds, k), lambda i: (0, 0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+        ],
+        out_specs=pl.BlockSpec((m, ds, k), lambda i: (0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((m, ds, k), jnp.float32),
+        interpret=interpret,
+    )(zt, wt, eps.reshape(tau).astype(jnp.float32))
+    return jnp.swapaxes(out, 1, 2)

@@ -105,14 +105,17 @@ class Profiler:
     def note_segment(self, *, program: Any, scheme: str, transport: str,
                      topology: str, m: int, n_windows: int, d: int,
                      kappa: int, tau: int, n_eval: int = 0,
-                     compiled: bool = False) -> None:
+                     compiled: bool = False, subspaces: int = 1) -> None:
         """Report one executed segment's shapes (a whole run for the fixed-M
-        executor; one per-M slice for an elastic run)."""
+        executor; one per-M slice for an elastic run).  A product quantizer
+        reports its codes per sub-codebook as ``kappa`` and its number of
+        sub-codebooks as ``subspaces``: a step's search is then 2 * kappa
+        * d operations, as a (kappa, d) codebook's is."""
         self._pending.append(dict(
             program=str(program), scheme=scheme, transport=transport,
             topology=topology, m=int(m), n_windows=max(int(n_windows), 1),
             d=int(d), kappa=int(kappa), tau=int(tau), n_eval=int(n_eval),
-            compiled=bool(compiled)))
+            compiled=bool(compiled), subspaces=int(subspaces)))
 
     def finish_run(self, wall_s: float) -> dict | None:
         """Attribute one run's measured wall across the pending segments.

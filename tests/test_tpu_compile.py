@@ -64,6 +64,53 @@ def test_window_kernel_compiles(one_chip, d, kappa):
     assert "tpu_custom_call" in text and "/vq_window/" in text
 
 
+def test_pq_window_kernel_compiles(one_chip):
+    """PQ16x256 on SIFT: m=16 sub-codebooks of k*=256 codes over 8-d
+    sub-vectors, tau=10."""
+    tau, m, k, ds = 10, 16, 256, 8
+    text = _compiled_text(
+        lambda z, w, eps: ops.pq_window(z, w, eps, interpret=False),
+        _f32(one_chip, tau, m * ds), _f32(one_chip, m, k, ds),
+        _f32(one_chip, tau))
+    assert "tpu_custom_call" in text and "/pq_window/" in text
+
+
+class _Compiled(Exception):
+    pass
+
+
+def test_pq_segment_names_its_kernel_and_probe(topo, monkeypatch):
+    """A PQ16x256 training segment compiled for one chip: the ``pq_window``
+    kernel sits under the ``local_window`` scope and the PQ probe under
+    ``eval_probe``, the op paths a profiler trace shows."""
+    import re
+
+    from repro.engine import MeshExecutor
+
+    monkeypatch.setattr(ops, "_interpret_default", lambda: False)
+    mesh = Mesh(np.array(topo.devices[:1]), ("workers",))
+    ex = MeshExecutor(mesh=mesh)
+    texts = []
+
+    def compile_only(cache_key, build, *args):
+        specs = (P(), P()) + (P("workers"),) * (len(args) - 2)
+        shapes = [jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=NamedSharding(mesh, s)), a)
+            for a, s in zip(args, specs)]
+        texts.append(build().lower(*shapes).compile().as_text())
+        raise _Compiled
+
+    ex._call_compiled = compile_only
+    with pytest.raises(_Compiled):
+        ex.run_segment("delta", jnp.zeros((16, 256, 8)),
+                       jnp.zeros((1, 200, 128)), jnp.zeros((1, 512, 128)),
+                       tau=10, mesh=mesh)
+    paths = [p.split("/") for p in re.findall(r'op_name="([^"]*)"', texts[0])]
+    assert any("local_window" in p and "pq_window" in p for p in paths)
+    assert any("eval_probe" in p for p in paths)
+    assert "tpu_custom_call" in texts[0]
+
+
 def test_per_step_delta_kernel_compiles(one_chip):
     """One point against kappa=4096 (the window is past the VMEM budget):
     the routed step pads to bm=8 rows and runs the full-codebook kernel."""
