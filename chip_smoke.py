@@ -255,8 +255,9 @@ def one_chip(jax, clock, seed: int) -> bool:
         prof = _text_profiler()
         ex = MeshExecutor(profiler=prof)
         res, _ = _train(jax, ph, ex, w0, data3, ev3, sim_w=sim.w_shared)
-        want = ("vq_window",) if route == "window" else ("vq_delta",)
-        absent = ("vq_delta",) if route == "window" else ("vq_window",)
+        want = ("vq_window",) if route == "window" else ("vq_assign",)
+        absent = (("vq_delta", "vq_assign") if route == "window"
+                  else ("vq_delta", "vq_window"))
         _kernel_check(ph, "\n".join(prof.texts), want, absent)
         ok &= ph.close()
         trained = res.w_shared

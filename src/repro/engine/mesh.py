@@ -25,11 +25,12 @@ every collective appends a ``CommRecord``, so ``last_comm`` reports the
 bytes the run actually moved (records traced per compiled program are
 replayed on compile-cache hits).
 
-The per-worker inner loop routes the nearest-prototype search through the
-fused Pallas kernel via ``kernels.ops.vq_delta_routed`` (interpret mode on
-CPU): codebooks that fit the VMEM budget take the fused kernel, larger
-ones the blocked-assign + segment-sum fallback — so the engine now honors
-the same larger-than-VMEM routing as the serving lookup.
+The per-worker inner loop runs on Pallas kernels (interpret mode on
+CPU): a window whose codebook fits the VMEM budget is one ``vq_window``
+dispatch; a larger one takes one ``vq_assign`` dispatch a step against
+carried row norms (the whole codebook as one block while it fits, the
+blocked grid past it) and updates only the winning row — the same
+larger-than-VMEM routing as the serving lookup.
 
 On CPU, force a mesh with ``--xla_force_host_platform_device_count=8`` (set
 before jax initializes; see tests/conftest.py) — the SPMD program is then
@@ -117,29 +118,55 @@ def _local_window(w0: jax.Array, zwin: jax.Array, t0: jax.Array, *,
         # VMEM-resident, eliminating tau-1 per-step kernel launches — the
         # step schedule is precomputed (it depends only on t0) and the
         # kernel replays the per-step float ops exactly, so this path is
-        # bit-identical to the scan below (the engine benchmark gates it)
+        # bit-identical to ``_step_window`` (the engine benchmark gates it)
         eps = vq.default_steps(t0 + 1 + jnp.arange(tau, dtype=jnp.int32),
                                eps0=eps0, decay=decay)
         w = ops.vq_window(zwin, w0, eps)
         return w0 - w, w
 
-    def body(carry, z):
-        w, t = carry
-        eps = vq.default_steps(t + 1, eps0=eps0, decay=decay)
-        if use_pallas:
-            # fused distance+argmin+scatter kernel (blocked fallback past
-            # the VMEM budget); batch of one point, so counts/zsum
-            # reduce exactly to eq. (4)'s H(z, w)
-            counts, zsum = ops.vq_delta_routed(z[None, :], w,
-                                               budget_bytes=vmem_budget,
-                                               fused=fused)
-            h = counts[:, None] * w - zsum
-        else:
-            h = vq.H(z, w)
-        return (w - eps * h, t + 1), None
+    if not use_pallas:
+        def body(carry, z):
+            w, t = carry
+            eps = vq.default_steps(t + 1, eps0=eps0, decay=decay)
+            return (w - eps * vq.H(z, w), t + 1), None
 
-    (w, _), _ = jax.lax.scan(body, (w0, t0), zwin)
+        (w, _), _ = jax.lax.scan(body, (w0, t0), zwin)
+        return w0 - w, w
+
+    w, _ = _step_window(w0, zwin, t0, eps0=eps0, decay=decay,
+                        vmem_budget=vmem_budget)
     return w0 - w, w
+
+
+def _step_window(w0: jax.Array, zwin: jax.Array, t0: jax.Array, *,
+                 eps0: float, decay: float, vmem_budget: int | None = None
+                 ) -> tuple[jax.Array, jax.Array]:
+    """tau sequential VQ steps, one assign-kernel dispatch each; returns
+    (w, the rows' squared norms (1, kappa)).
+
+    The window kernel's step, taken one dispatch at a time: the norms are
+    computed once on entry and carried, the assign kernel finds the
+    winner against them, and only the winning row and its norm change
+    (eq. 4's H is zero on every other row).  Nothing of (kappa, d) size
+    is formed per step beyond the distance product."""
+
+    def step(carry, z):
+        w, w2, t = carry
+        eps = vq.default_steps(t + 1, eps0=eps0, decay=decay)
+        arg, _ = ops.vq_assign_routed(z[None, :], w, w2=w2,
+                                      budget_bytes=vmem_budget)
+        k = arg[0]
+        row = jax.lax.dynamic_slice_in_dim(w, k, 1)              # (1, d)
+        h = row - z[None, :]
+        row = row - eps * h
+        w = jax.lax.dynamic_update_slice_in_dim(w, row, k, axis=0)
+        w2 = jax.lax.dynamic_update_slice_in_dim(
+            w2, jnp.sum(row * row, axis=1, keepdims=True), k, axis=1)
+        return (w, w2, t + 1), None
+
+    w2 = jnp.sum(w0 * w0, axis=1)[None, :]
+    (w, w2, _), _ = jax.lax.scan(step, (w0, w2, t0), zwin)
+    return w, w2
 
 
 class MeshExecutor:
@@ -200,11 +227,11 @@ class MeshExecutor:
         self.transport = comm.get_transport(
             transport if transport is not None else "xla")
         self.use_pallas = use_pallas
-        # fused=True rides the one-dispatch Pallas hot path (window kernel
-        # when the codebook fits VMEM, fused blocked assign+delta past it)
-        # plus the double-buffered publish drain; fused=False keeps the
-        # per-step scan + XLA segment-sum route as the benchmark comparator.
-        # Both are bit-identical — the flag trades dispatches, not math.
+        # fused=True rides the one-dispatch window kernel when the codebook
+        # fits VMEM (the per-step assign route past it) plus the
+        # double-buffered publish drain; fused=False takes the per-step
+        # route at every size, the benchmark's comparator.  Both are
+        # bit-identical — the flag trades dispatches, not math.
         self.fused = fused
         self.eval_every = eval_every
         self.vmem_budget_bytes = vmem_budget_bytes

@@ -125,23 +125,59 @@ def _tiles(z: jax.Array, w: jax.Array, bm: int | None, bk: int | None,
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bk", "interpret"))
-def _vq_assign(z, w, *, bm: int, bk: int, interpret: bool):
+def _vq_assign(z, w, w2=None, *, bm: int, bk: int, interpret: bool):
     batch, kappa = z.shape[0], w.shape[0]
     bm_ = min(bm, max(_bm_floor(interpret), batch))
     zp = _pad_rows(z, bm_)
     wp = _pad_rows(w, bk)
+    if w2 is not None and wp.shape[0] > kappa:
+        w2 = jnp.pad(w2, ((0, 0), (0, wp.shape[0] - kappa)))
     assign, mind = _k.vq_assign_pallas(zp, wp, bm=bm_, bk=min(bk, wp.shape[0]),
-                                       kappa_valid=kappa, interpret=interpret)
+                                       kappa_valid=kappa, w2=w2,
+                                       interpret=interpret)
     return assign[:batch], mind[:batch]
 
 
-def vq_assign(z: jax.Array, w: jax.Array, *, bm: int | None = None,
-              bk: int | None = None,
+def vq_assign(z: jax.Array, w: jax.Array, *, w2: jax.Array | None = None,
+              bm: int | None = None, bk: int | None = None,
               interpret: bool | None = None) -> tuple[jax.Array, jax.Array]:
-    """Nearest-prototype assignment; same contract as ``ref.vq_assign_ref``."""
+    """Nearest-prototype assignment; same contract as ``ref.vq_assign_ref``.
+
+    ``w2``: the rows' squared norms, (1, kappa) f32, for a caller that
+    keeps them up to date (the engine's per-step route); omitted, the
+    kernel's wrapper computes them."""
     interpret = _interpret_default() if interpret is None else interpret
     bm, bk = _tiles(z, w, bm, bk, "assign")
-    return _vq_assign(z, w, bm=bm, bk=bk, interpret=interpret)
+    return _vq_assign(z, w, w2, bm=bm, bk=bk, interpret=interpret)
+
+
+def assign_vmem_bytes(kappa: int, d: int, *, bm: int, bk: int,
+                      dtype_bytes: int = 4) -> int:
+    """VMEM residency of one ``vq_assign`` grid step: the (bm, d) point
+    block and its (bm, 1) norms, the (bk, d) codebook block and its (1,
+    bk) norms, the (bm, bk) distance and index tiles, and the two (bm, 1)
+    running outputs."""
+    return dtype_bytes * (bm * d + bm + bk * d + bk + 2 * bm * bk + 2 * bm)
+
+
+def vq_assign_routed(z: jax.Array, w: jax.Array, *,
+                     w2: jax.Array | None = None,
+                     budget_bytes: int | None = None,
+                     interpret: bool | None = None
+                     ) -> tuple[jax.Array, jax.Array]:
+    """``vq_assign`` with VMEM-aware tiles (same contract).
+
+    When the whole (kappa, d) codebook fits the VMEM budget beside the
+    small tiles, it is one block: a grid of one along kappa, one pass over
+    the codebook.  Otherwise the tuner's blocked grid runs.  The tuner's
+    candidates stop at ``bk`` = 512, so leaving a resident codebook to it
+    would split the search into kappa / 512 grid steps."""
+    kappa, d = w.shape
+    bm, bk = _tiles(z, w, None, None, "assign", budget_bytes=budget_bytes)
+    if (assign_vmem_bytes(kappa, d, bm=min(bm, max(8, z.shape[0])), bk=kappa)
+            <= vmem_budget_bytes(budget_bytes)):
+        bk = kappa
+    return vq_assign(z, w, w2=w2, bm=bm, bk=bk, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "interpret"))
@@ -188,8 +224,8 @@ def _delta_via_assign(z: jax.Array, w: jax.Array, *, bm: int, bk: int,
     """(counts, zsum) through the blocked assignment kernel + a segment sum.
 
     The pre-fusion blocked route: the assignments round-trip through HBM and
-    the scatter-add back to (kappa, d) happens in XLA.  Kept as the
-    ``fused=False`` comparator the engine benchmark gates against.
+    the scatter-add back to (kappa, d) happens in XLA.  Kept as
+    ``vq_delta_routed``'s ``fused=False`` comparator.
     """
     assign, _ = vq_assign(z, w, bm=bm, bk=bk, interpret=interpret)
     kappa, d = w.shape
@@ -261,9 +297,10 @@ def vq_window(zwin: jax.Array, w0: jax.Array, eps: jax.Array, *,
               interpret: bool | None = None) -> jax.Array:
     """One fused window: tau sequential eq.-1 steps in a single dispatch.
 
-    Bit-identical to scanning ``vq_delta_routed`` + the eq.-8 update over
-    the rows of ``zwin`` (the engine gates this).  Callers check
-    ``window_fits_vmem`` first — the codebook stays resident throughout.
+    Bit-identical to scanning ``vq_assign`` and the one-row eq.-1 update
+    over the rows of ``zwin``, the engine's per-step route (the engine
+    gates this).  Callers check ``window_fits_vmem`` first — the codebook
+    stays resident throughout.
     """
     interpret = _interpret_default() if interpret is None else interpret
     return _f.vq_window_pallas(zwin, w0, eps, interpret=interpret)

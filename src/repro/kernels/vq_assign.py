@@ -69,10 +69,13 @@ def _assign_kernel(z_ref, w_ref, z2_ref, w2_ref, assign_ref, mind_ref,
 
 def vq_assign_pallas(z: jax.Array, w: jax.Array, *, bm: int = 128,
                      bk: int = 128, kappa_valid: int | None = None,
+                     w2: jax.Array | None = None,
                      interpret: bool = False) -> tuple[jax.Array, jax.Array]:
     """(batch, d), (kappa, d) -> assign (batch,) int32, mindist (batch,) f32.
 
     batch % bm == 0 and kappa % bk == 0 are required (ops.py pads).
+    ``w2`` is the rows' squared norms, (1, kappa) f32, when the caller
+    keeps them; without it they are computed here.
     """
     batch, d = z.shape
     kappa, _ = w.shape
@@ -80,7 +83,8 @@ def vq_assign_pallas(z: jax.Array, w: jax.Array, *, bm: int = 128,
     z32 = z.astype(jnp.float32)
     w32 = w.astype(jnp.float32)
     z2 = jnp.sum(z32 * z32, axis=1, keepdims=True)          # (batch, 1)
-    w2 = jnp.sum(w32 * w32, axis=1)[None, :]                # (1, kappa)
+    if w2 is None:
+        w2 = jnp.sum(w32 * w32, axis=1)[None, :]            # (1, kappa)
 
     grid = (batch // bm, kappa // bk)
     assign, mind = pl.pallas_call(

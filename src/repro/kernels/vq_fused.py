@@ -22,13 +22,13 @@ Two fusions on top of the ``vq_assign.py`` pair:
   * ``vq_window_pallas`` — the engine's inner loop: ``tau`` SEQUENTIAL
     eq.-1 steps (batch of one point each) fused into one dispatch with the
     codebook resident in VMEM for the whole window.  Each step runs the
-    same float ops as the per-step path (d2 via MXU contraction, strict
-    argmin, ``w - eps*(counts*w - zsum)``) on single-row operands, but
-    only on the winning row: the losing rows' update is the identity, so
-    the fused window is bit-identical to the per-step scan it replaces —
-    every per-row reduction and product is independent of the seven
-    padding rows the unfused kernel carries.  That bit-stability is gated
-    by the engine benchmark's fused-vs-unfused records.
+    same float ops as the engine's per-step route (d2 via MXU contraction
+    against carried row norms, strict argmin, ``row - eps*(row - z)`` on
+    the winning row alone), so the fused window is bit-identical to the
+    per-step scan it replaces — every per-row reduction and product is
+    independent of the seven padding rows the per-step kernel carries.
+    That bit-stability is gated by the engine benchmark's
+    fused-vs-unfused records.
 
   * ``pq_window_pallas`` — the same window for a product quantizer: m
     sub-codebooks of k codes over d/m-dimensional sub-vectors, all
@@ -260,8 +260,8 @@ def _window_kernel(z_ref, w0_ref, eps_ref, wout_ref, w2_ref, *, tau: int):
     two compilation artifacts can silently break it:
 
       * SHAPES: XLA's reduction/matmul emission is shape-dependent, so the
-        distance ops here must see the SAME shapes as ``_delta_kernel``
-        does on the per-step path.  On the interpret backend ``ops.py``
+        distance ops here must see the SAME shapes as ``_assign_kernel``
+        does on the per-step route.  On the interpret backend ``ops.py``
         clamps the batch-of-one block to one row (no MXU to align for), so
         each step here computes z2/dot/argmin on the matching (1, d)
         row, and the cross term is spelled ``z @ w.T`` exactly as
@@ -275,9 +275,10 @@ def _window_kernel(z_ref, w0_ref, eps_ref, wout_ref, w2_ref, *, tau: int):
         differently from either loop, and matching those breaks the
         jitted-scan equality that actually matters.
 
-    Updating only the winner is the same arithmetic as the per-step
-    path's full ``w - eps*(counts*w - zsum)``: on a losing row that
-    returns ``w`` unchanged, on the winning row it is ``w - eps*(w - z)``.
+    Updating only the winner is the same arithmetic as the minibatch
+    form ``w - eps*(counts*w - zsum)`` at a batch of one: on a losing row
+    that returns ``w`` unchanged, on the winning row it is
+    ``w - eps*(w - z)``.
     """
     kappa = w0_ref.shape[0]
     w0 = w0_ref[...].astype(jnp.float32)

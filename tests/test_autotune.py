@@ -108,15 +108,23 @@ def test_window_kernel_bitwise_matches_per_step_scan(case):
     eps = vq.default_steps(1 + jnp.arange(tau, dtype=jnp.int32))
     w_fused = ops.vq_window(zwin, w0, eps)
 
-    # the engine's pre-fusion per-step path, verbatim (mesh._local_window's
-    # scan body) — the fused kernel replays these float ops exactly
+    # the engine's per-step route, verbatim (mesh._step_window's scan
+    # body) — the fused kernel replays these float ops exactly
     def scan_oracle(zwin, w0, eps):
-        def body(w, ze):
+        def body(carry, ze):
+            w, w2 = carry
             z, e = ze
-            counts, zsum = ops.vq_delta(z[None, :], w)
-            h = counts[:, None] * w - zsum
-            return w - e * h, None
-        return jax.lax.scan(body, w0, (zwin, eps))[0]
+            arg, _ = ops.vq_assign_routed(z[None, :], w, w2=w2)
+            k = arg[0]
+            row = jax.lax.dynamic_slice_in_dim(w, k, 1)
+            h = row - z[None, :]
+            row = row - e * h
+            w = jax.lax.dynamic_update_slice_in_dim(w, row, k, axis=0)
+            w2 = jax.lax.dynamic_update_slice_in_dim(
+                w2, jnp.sum(row * row, axis=1, keepdims=True), k, axis=1)
+            return (w, w2), None
+        w2 = jnp.sum(w0 * w0, axis=1)[None, :]
+        return jax.lax.scan(body, (w0, w2), (zwin, eps))[0][0]
 
     w_ref = jax.jit(scan_oracle)(zwin, w0, eps)
     # fusion trades dispatches, not math: BITWISE equality, not allclose

@@ -122,6 +122,102 @@ def test_per_step_delta_kernel_compiles(one_chip):
     assert "tpu_custom_call" in text and "/vq_delta/" in text
 
 
+def test_per_step_route_compiles_with_one_assign_block(one_chip,
+                                                      monkeypatch):
+    """A tau=10 window at kappa=4096, d=128 (past the window kernel's VMEM
+    budget): one ``vq_assign`` call a step over the whole codebook as one
+    block, a grid of one, and no ``vq_delta``."""
+    import re
+
+    from repro.engine.mesh import _local_window
+
+    kappa, d, tau = 4096, 128, 10
+    assert not ops.window_fits_vmem(kappa, d, tau)
+    monkeypatch.setattr(ops, "_interpret_default", lambda: False)
+    lowered = jax.jit(lambda w, z, t: _local_window(
+        w, z, t, eps0=0.5, decay=1.0, use_pallas=True)[1]).lower(
+        _f32(one_chip, kappa, d), _f32(one_chip, tau, d),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip))
+    stablehlo = lowered.as_text()
+    assert re.findall(r'kernel_name = "(\w+)"', stablehlo) == ["vq_assign"]
+    assert ("iteration_bounds = array<i64: 1, 1>"
+            in _without_kernel_locations(stablehlo))
+    text = lowered.compile().as_text()
+    assert "tpu_custom_call" in text and "/vq_assign/" in text
+    assert "/vq_delta/" not in text
+
+
+def _without_kernel_locations(text: str) -> str:
+    """Lowered text with each Mosaic kernel body printed without its
+    debug locations (they name the file and line of each call)."""
+    import base64
+    import re
+
+    from jax._src.lib.mlir import ir
+
+    def asm(m):
+        ctx = ir.Context()
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            module = ir.Module.parse(base64.b64decode(m.group(1)))
+            return module.operation.get_asm(enable_debug_info=False)
+    return re.sub(r"\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22", asm, text)
+
+
+def test_serve_lookup_program_unchanged_without_norms(one_chip):
+    """Serving calls ``vq_assign`` without kept norms: its program is the
+    one the wrapper built before it took them (copied below as it was)."""
+    import functools
+
+    from jax.experimental import pallas as pl
+
+    from repro.kernels import vq_assign as _k
+
+    def assign_pallas(z, w, *, bm, bk, kappa_valid, interpret):
+        batch, d = z.shape
+        kappa, _ = w.shape
+        z32 = z.astype(jnp.float32)
+        w32 = w.astype(jnp.float32)
+        z2 = jnp.sum(z32 * z32, axis=1, keepdims=True)
+        w2 = jnp.sum(w32 * w32, axis=1)[None, :]
+        assign, mind = pl.pallas_call(
+            functools.partial(_k._assign_kernel, bk=bk,
+                              kappa_valid=kappa_valid),
+            name="vq_assign",
+            grid=(batch // bm, kappa // bk),
+            in_specs=[pl.BlockSpec((bm, d), lambda i, j: (i, 0)),
+                      pl.BlockSpec((bk, d), lambda i, j: (j, 0)),
+                      pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),
+                      pl.BlockSpec((1, bk), lambda i, j: (0, j))],
+            out_specs=[pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),
+                       pl.BlockSpec((bm, 1), lambda i, j: (i, 0))],
+            out_shape=[jax.ShapeDtypeStruct((batch, 1), jnp.int32),
+                       jax.ShapeDtypeStruct((batch, 1), jnp.float32)],
+            interpret=interpret,
+        )(z, w, z2, w2)
+        return assign[:, 0], mind[:, 0]
+
+    @functools.partial(jax.jit, static_argnames=("bm", "bk", "interpret"))
+    def _vq_assign(z, w, *, bm, bk, interpret):
+        batch, kappa = z.shape[0], w.shape[0]
+        bm_ = min(bm, max(ops._bm_floor(interpret), batch))
+        zp = ops._pad_rows(z, bm_)
+        wp = ops._pad_rows(w, bk)
+        assign, mind = assign_pallas(zp, wp, bm=bm_,
+                                     bk=min(bk, wp.shape[0]),
+                                     kappa_valid=kappa, interpret=interpret)
+        return assign[:batch], mind[:batch]
+
+    shapes = (_f32(one_chip, 128, 128), _f32(one_chip, 4096, 128))
+    bm, bk = ops._tiles(*shapes, None, None, "assign")
+    now, before = (
+        _without_kernel_locations(
+            f.lower(*shapes, bm=bm, bk=bk, interpret=False).as_text())
+        for f in (ops._vq_assign, _vq_assign))
+    assert "tpu_custom_call" in now
+    assert now == before
+
+
 def test_assign_kernel_compiles(one_chip):
     """The serving lookup's kernel, with the tiles the tuner picks."""
     text = _compiled_text(lambda z, w: ops.vq_assign(z, w, interpret=False),
