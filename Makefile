@@ -2,8 +2,7 @@
 #
 #   make test          — tier-1 verify (full pytest suite, 8 forced devices)
 #   make bench-smoke   — quick benchmark pass: engine executor suite
-#   make bench-engine  — full Sim-vs-Mesh executor benchmark + the per-scheme
-#                        fused-vs-unfused kernel legs -> BENCH_engine.json
+#   make bench-engine  — full Sim-vs-Mesh executor benchmark -> BENCH_engine.json
 #   make bench-elastic — elastic resize-event cost benchmark -> BENCH_elastic.json
 #   make bench-serve   — serving suite (lookup/service/hot-swap) -> BENCH_serve.json
 #   make bench-comm    — scheme x transport wall + measured wire bytes -> BENCH_comm.json

@@ -12,13 +12,7 @@ when:
     times are not comparable across machines, the ratio is); or
   * any distortion curve diverges from the baseline beyond ``--curve-rtol``
     (the runs are seeded, so the curves are a numerical fingerprint of the
-    engine — a drift means the schemes no longer compute what they did); or
-  * the fused-vs-unfused leg regresses: the kernel-fusion wall ratio
-    (``MeshExecutor(fused=True)`` over ``fused=False``, same box so the
-    machine divides out) exceeds 1.0 on every sync scheme (min over legs,
-    the same flap-proof statistic as the mesh/sim gate), or any scheme's
-    fused distortion curve is no longer BITWISE equal to the unfused one
-    (fusion trades dispatches, never math).
+    engine — a drift means the schemes no longer compute what they did).
 
 The mesh/sim ratio normalizes the machine out of the comparison as far as
 one number can: both executors ran the same work on the same box.  It is
@@ -185,10 +179,7 @@ def gate_table(gates: list[dict]) -> str:
 
 
 def _index(doc: dict) -> dict[tuple[str, int], dict]:
-    # kind-less records are the sim/mesh trajectory legs; 'fusion' records
-    # carry no trajectory and ride their own gate in ``check``
-    return {(r["executor"], r["m"]): r for r in doc.get("results", [])
-            if r.get("kind") is None}
+    return {(r["executor"], r["m"]): r for r in doc.get("results", [])}
 
 
 def _config_key(rec: dict) -> tuple:
@@ -259,44 +250,6 @@ def check(baseline: dict, fresh: dict, *, max_ratio_regression: float = 1.25,
             msgs.append(f"ok   {key}: curve max rel err {err:.2e}")
     _gate(gates, "engine distortion curve max rel err", max_err, curve_rtol)
 
-    # -- kernel fusion: fused vs unfused mesh runs on the SAME box, so the
-    # ratio is machine-free and gated ABSOLUTELY (fused must not be slower).
-    # Min over the sync legs is the flap-proof statistic: a genuine fusion
-    # regression slows every leg, noise does not.  Bitwise curve equality
-    # is functional and gated per leg — fusion trades dispatches, not math.
-    b_fu = {r["scheme"]: r for r in baseline.get("results", [])
-            if r.get("kind") == "fusion"}
-    f_fu = {r["scheme"]: r for r in fresh.get("results", [])
-            if r.get("kind") == "fusion"}
-    if b_fu and not f_fu:
-        raise ValueError("fresh engine run has no fusion records but the "
-                         "baseline does — the suite lost coverage "
-                         "(regenerate with benchmarks.run --suite engine)")
-    if f_fu:
-        sync = sorted(s for s, r in f_fu.items() if r.get("sync"))
-        if sync:
-            best = min(f_fu[s]["fused_over_unfused"] for s in sync)
-            _gate(gates, "engine fused/unfused wall (min sync leg)",
-                  best, 1.0)
-            per = ", ".join(f"{s} {f_fu[s]['fused_over_unfused']:.2f}x"
-                            for s in sync)
-            line = f"fused/unfused wall over sync legs: {per} (min {best:.2f}x)"
-            if best > 1.0:
-                ok = False
-                msgs.append(f"FAIL {line} > 1.00x — fusion no longer pays")
-            else:
-                msgs.append(f"ok   {line}")
-        mismatched = sorted(s for s, r in f_fu.items()
-                            if not r.get("curve_bitmatch"))
-        _gate(gates, "engine fusion curve bit-mismatch legs",
-              len(mismatched), 0)
-        if mismatched:
-            ok = False
-            msgs.append(f"FAIL fused curves diverged bitwise from unfused "
-                        f"on {mismatched} — fusion changed the math")
-        else:
-            msgs.append(f"ok   fused curves bitwise equal to unfused on "
-                        f"all {len(f_fu)} scheme legs")
     return ok, msgs
 
 
@@ -973,8 +926,7 @@ def variance_warnings(doc: dict, *, threshold: float,
     rather than widening the gate).  Never fails the run."""
     warns: list[str] = []
     for rec in doc.get("results", []):
-        for fld in ("wall_samples", "wall_samples_off", "wall_samples_on",
-                    "wall_samples_fused", "wall_samples_unfused"):
+        for fld in ("wall_samples", "wall_samples_off", "wall_samples_on"):
             s = rec.get(fld)
             if not isinstance(s, list) or len(s) < 2 or min(s) <= 0:
                 continue

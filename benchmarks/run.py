@@ -180,15 +180,7 @@ def bench_engine(*, quick: bool = False,
     Each executor runs the delta scheme end to end (compile excluded via a
     warm-up run); "per point" divides by the M*n points the run consumes, so
     the number is the engine's cost of one unit of the paper's work.  Writes
-    the full trajectory record to ``BENCH_engine.json``.
-
-    A second leg runs each scheme at M=8 on the mesh executor with kernel
-    fusion on vs off (``MeshExecutor(fused=...)``) — same data, same seeds,
-    the only difference is one-dispatch window/delta kernels plus the
-    overlapped publish drain.  Both walls are measured on the same box, so
-    the fused/unfused ratio is machine-free and ``check_regression`` gates
-    it (sync legs must not be slower fused) along with bitwise curve
-    equality."""
+    the full trajectory record to ``BENCH_engine.json``."""
     from repro.data import synthetic
     from repro.engine import InstantNetwork, get_executor
 
@@ -227,42 +219,6 @@ def bench_engine(*, quick: bool = False,
                                          np.float64).tolist(),
             })
 
-    # -- fused vs unfused, per scheme, M=8 (data/w0 left from the loop).
-    # async_delta's per-tick program is identical at these shapes (the
-    # blocked route isn't taken), so only the sync legs carry a wall gate;
-    # every leg pins bitwise curve equality — fusion trades dispatches,
-    # never math.
-    m = 8
-    for scheme in ("delta", "average", "async_delta"):
-        walls, curves = {}, {}
-        for fused in (True, False):
-            ex = get_executor("mesh", network=InstantNetwork(), fused=fused)
-            jax.block_until_ready(
-                ex.run(scheme, w0, data, eval_data, tau=tau).w_shared)
-            samples = []
-            for _ in range(3):
-                t0 = time.perf_counter()
-                res = ex.run(scheme, w0, data, eval_data, tau=tau)
-                jax.block_until_ready(res.w_shared)
-                samples.append(time.perf_counter() - t0)
-            walls[fused] = samples
-            curves[fused] = np.asarray(res.distortion)
-        ratio = min(walls[True]) / max(min(walls[False]), 1e-12)
-        bitmatch = bool(np.array_equal(curves[True], curves[False]))
-        rows.append(f"engine_fusion_{scheme},{min(walls[True]) * 1e6:.0f},"
-                    f"fused_over_unfused={ratio:.3f}"
-                    f" curve_bitmatch={bitmatch}")
-        records.append({
-            "kind": "fusion", "executor": f"fusion:{scheme}",
-            "scheme": scheme, "m": m, "n": n, "d": d, "kappa": kappa,
-            "tau": tau, "sync": scheme != "async_delta",
-            "wall_fused_s": min(walls[True]),
-            "wall_unfused_s": min(walls[False]),
-            "fused_over_unfused": ratio,
-            "wall_samples_fused": walls[True],
-            "wall_samples_unfused": walls[False],
-            "curve_bitmatch": bitmatch,
-        })
     with open(out_path, "w") as f:
         json.dump({"suite": "engine", "devices": len(jax.devices()),
                    "backend": jax.default_backend(),

@@ -171,7 +171,6 @@ class ElasticMeshExecutor:
 
     def __init__(self, schedule, network: NetworkModel | None = None,
                  axis: str = "workers", *, use_pallas: bool = True,
-                 fused: bool = True,
                  transport: comm.Transport | str | None = None,
                  topology: Topology | None = None,
                  checkpointer=None, resume: bool = False,
@@ -213,7 +212,6 @@ class ElasticMeshExecutor:
             axis = topology.worker_axis
         self.axis = axis
         self.use_pallas = use_pallas
-        self.fused = fused
         # ONE transport shared by every per-M segment executor, so the whole
         # elastic run streams into a single CommLog (segments + late deltas)
         self.transport = comm.get_transport(
@@ -283,7 +281,6 @@ class ElasticMeshExecutor:
                 self._mesh_ex[m] = MeshExecutor(
                     topology=topo, network=self.network,
                     transport=self.transport, use_pallas=self.use_pallas,
-                    fused=self.fused,
                     merge=self.merge, quorum_frac=self.quorum_frac,
                     staleness_gamma=self.staleness_gamma,
                     tracer=self.tracer, metrics=self.metrics,
@@ -295,7 +292,7 @@ class ElasticMeshExecutor:
                 self._mesh_ex[m] = MeshExecutor(
                     mesh=mesh, axis=self.axis, network=self.network,
                     transport=self.transport, use_pallas=self.use_pallas,
-                    fused=self.fused, merge=self.merge, quorum_frac=self.quorum_frac,
+                    merge=self.merge, quorum_frac=self.quorum_frac,
                     staleness_gamma=self.staleness_gamma,
                     tracer=self.tracer, metrics=self.metrics,
                     profiler=self.profiler)

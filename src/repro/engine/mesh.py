@@ -93,7 +93,7 @@ def _validate_mesh(mesh: Mesh, axes: tuple[str, ...], m: int) -> None:
 
 def _local_window(w0: jax.Array, zwin: jax.Array, t0: jax.Array, *,
                   eps0: float, decay: float, use_pallas: bool,
-                  vmem_budget: int | None = None, fused: bool = True
+                  vmem_budget: int | None = None
                   ) -> tuple[jax.Array, jax.Array]:
     """tau sequential VQ steps (eq. 1) on one device; returns (delta, w).
 
@@ -111,14 +111,13 @@ def _local_window(w0: jax.Array, zwin: jax.Array, t0: jax.Array, *,
                                               None), w0, (zwin, eps))
         return w0 - w, w
     kappa, d = w0.shape
-    if (use_pallas and fused
-            and ops.window_fits_vmem(kappa, d, tau,
-                                     budget_bytes=vmem_budget)):
+    if use_pallas and ops.window_fits_vmem(kappa, d, tau,
+                                           budget_bytes=vmem_budget):
         # whole window in ONE Pallas dispatch: tau steps with the codebook
         # VMEM-resident, eliminating tau-1 per-step kernel launches — the
         # step schedule is precomputed (it depends only on t0) and the
         # kernel replays the per-step float ops exactly, so this path is
-        # bit-identical to ``_step_window`` (the engine benchmark gates it)
+        # bit-identical to ``_step_window`` (tests/test_step_route.py)
         eps = vq.default_steps(t0 + 1 + jnp.arange(tau, dtype=jnp.int32),
                                eps0=eps0, decay=decay)
         w = ops.vq_window(zwin, w0, eps)
@@ -178,7 +177,7 @@ class MeshExecutor:
                  network: NetworkModel | None = None, *,
                  topology: Topology | None = None,
                  transport: comm.Transport | str | None = None,
-                 use_pallas: bool = True, fused: bool = True,
+                 use_pallas: bool = True,
                  eval_every: int = 10,
                  vmem_budget_bytes: int | None = None,
                  on_window: Callable[[int, jax.Array], None] | None = None,
@@ -227,12 +226,6 @@ class MeshExecutor:
         self.transport = comm.get_transport(
             transport if transport is not None else "xla")
         self.use_pallas = use_pallas
-        # fused=True rides the one-dispatch window kernel when the codebook
-        # fits VMEM (the per-step assign route past it) plus the
-        # double-buffered publish drain; fused=False takes the per-step
-        # route at every size, the benchmark's comparator.  Both are
-        # bit-identical — the flag trades dispatches, not math.
-        self.fused = fused
         self.eval_every = eval_every
         self.vmem_budget_bytes = vmem_budget_bytes
         # merge override: None = the scheme's own strategy (the default,
@@ -474,12 +467,12 @@ class MeshExecutor:
         chunks exactly as it threads across the scan), at most two extra
         compiled programs (the chunk shape and one remainder shape).
 
-        The drain is DOUBLE-BUFFERED (when ``fused`` is on): chunk k+1 is
-        dispatched before chunk k's host-side reads (``np.asarray`` on the
-        curve, the tick conversion, the ``on_window`` publish) block on its
-        result — the latency-hiding pattern ``comm/ring.py`` uses for
-        neighbor hops, lifted to the host loop, so the merge collective at
-        the tail of one chunk overlaps the next chunk's compute.  The same
+        The drain is DOUBLE-BUFFERED: chunk k+1 is dispatched before
+        chunk k's host-side reads (``np.asarray`` on the curve, the tick
+        conversion, the ``on_window`` publish) block on its result — the
+        latency-hiding pattern ``comm/ring.py`` uses for neighbor hops,
+        lifted to the host loop, so the merge collective at the tail of
+        one chunk overlaps the next chunk's compute.  The same
         programs run in the same order with the same inputs (chunk k+1
         depends on chunk k only through device arrays), so the pipelining
         is bit-stable; ``on_window`` still fires in chunk order."""
@@ -512,10 +505,7 @@ class MeshExecutor:
             w = res.w_shared     # device-side dependency only: no host sync
             if pending is not None:
                 wt = drain(pending, wt)
-            if self.fused:
-                pending = (res, done)
-            else:
-                wt = drain((res, done), wt)
+            pending = (res, done)
             done += k
             t += k * tau
             if self.tier1_controller is not None:
@@ -604,7 +594,6 @@ class MeshExecutor:
             strategy = merge_lib.get_merge(scheme, transport=self.transport)
         transport = self.transport
         use_pallas = self.use_pallas
-        fused = self.fused
         vmem_budget = self.vmem_budget_bytes
         probe = vq.pq_distortion if w0.ndim == 3 else vq.distortion
         if merge_state is None:
@@ -640,8 +629,7 @@ class MeshExecutor:
                 with jax.named_scope(LOCAL_WINDOW_SCOPE):
                     _, w_fin = _local_window(
                         w_srd, zwin, t, eps0=eps0, decay=decay,
-                        use_pallas=use_pallas, vmem_budget=vmem_budget,
-                        fused=fused)
+                        use_pallas=use_pallas, vmem_budget=vmem_budget)
                 late = {"late": x[1]} if quorum else {}
                 with jax.named_scope(MERGE_SCOPE):
                     w_srd, ms = strategy(w_srd, w_fin, axis, ms,
@@ -680,7 +668,7 @@ class MeshExecutor:
             return w_srd, ys, ms_out
 
         cache_key = ("sync", scheme, mesh, w0.shape, data.shape,
-                     eval_data.shape, tau, eps0, decay, use_pallas, fused,
+                     eval_data.shape, tau, eps0, decay, use_pallas,
                      vmem_budget, observe, self._transport_frac_key())
         if quorum:
             cache_key += ("quorum", self.quorum_frac, self.staleness_gamma)
@@ -897,7 +885,6 @@ class MeshExecutor:
         eval_ticks = np.arange(eval_every - 1, n, eval_every)
         transport = self.transport
         use_pallas = self.use_pallas
-        fused = self.fused
         vmem_budget = self.vmem_budget_bytes
 
         def body(w0_in, data_l, eval_l, done_at_l):
@@ -911,7 +898,7 @@ class MeshExecutor:
                 # local VQ step (1st line of eq. 9), Pallas hot path
                 if use_pallas:
                     counts, zsum = ops.vq_delta_routed(
-                        z[None, :], w, budget_bytes=vmem_budget, fused=fused)
+                        z[None, :], w, budget_bytes=vmem_budget)
                     h = counts[:, None] * w - zsum
                 else:
                     h = vq.H(z, w)
@@ -955,8 +942,7 @@ class MeshExecutor:
             return w_srd_final, curve
 
         cache_key = ("async", mesh, w0.shape, data.shape, eval_data.shape,
-                     tau, eps0, decay, eval_every, use_pallas, fused,
-                     vmem_budget)
+                     tau, eps0, decay, eval_every, use_pallas, vmem_budget)
 
         def build():
             return jax.jit(jax.shard_map(

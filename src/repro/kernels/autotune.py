@@ -14,16 +14,12 @@ where ``delta_hbm_bytes`` counts the blocked kernel's refetch traffic —
 larger tiles mean fewer refetches, so the model pushes tiles as large as
 the budget allows, then grid size breaks ties deterministically.
 
-Three modes, set once at launch (``--autotune {off,cache,search}``):
+Two modes, set once at launch (``--autotune {off,cache}``):
 
   * ``off``    — legacy fixed (128, 128) tiles, no cache touched.
   * ``cache``  — model-picked tiles, memoized in-process and (optionally)
                  in a JSON file (``REPRO_AUTOTUNE_CACHE=path`` or
                  ``set_cache_path``).  Same shape => same config, always.
-  * ``search`` — model ranks candidates, then the top ``SEARCH_TOP_N`` are
-                 actually timed (best-of-3 jitted walls on synthetic data)
-                 and the fastest wins.  Results land in the same cache, so
-                 a hit never re-searches.
 
 The JSON cache is keyed by the full tune key INCLUDING the device kind, so
 a file tuned on one accelerator never leaks tiles to another.
@@ -38,11 +34,9 @@ import threading
 
 from repro.distributed.roofline import VqCell, device_peaks
 
-MODES = ("off", "cache", "search")
+MODES = ("off", "cache")
 DEFAULT_TILES = (128, 128)          # the pre-autotune hardcoded tiles
 CANDIDATE_TILES = (8, 16, 32, 64, 128, 256, 512)
-SEARCH_TOP_N = 3                    # model-ranked candidates timed in search
-SEARCH_BATCH_REPS = 3               # best-of walls per timed candidate
 
 
 @dataclasses.dataclass(frozen=True)
@@ -57,7 +51,7 @@ class _TunerState:
         self.cache: dict[str, TileConfig] = {}
         self.cache_path: str | None = None
         self.file_loaded = False
-        self.searches = 0            # model/search evaluations (cache misses)
+        self.searches = 0            # model evaluations (cache misses)
         self.lock = threading.Lock()
 
 
@@ -152,28 +146,6 @@ def _rank(cands: list[TileConfig], batch: int, kappa: int, d: int,
     return sorted(cands, key=score)
 
 
-def _measure(cfg: TileConfig, batch: int, kappa: int, d: int) -> float:
-    """Best-of-N jitted wall for one fused-delta dispatch (search mode)."""
-    import time
-
-    import jax
-    import jax.numpy as jnp
-
-    from repro.kernels import ops
-
-    key = jax.random.PRNGKey(0)
-    z = jax.random.normal(key, (batch, d), jnp.float32)
-    w = jax.random.normal(jax.random.fold_in(key, 1), (kappa, d), jnp.float32)
-    fn = jax.jit(lambda z, w: ops.vq_delta_routed(z, w, bm=cfg.bm, bk=cfg.bk))
-    jax.block_until_ready(fn(z, w))   # compile outside the timed region
-    best = float("inf")
-    for _ in range(SEARCH_BATCH_REPS):
-        t0 = time.perf_counter()
-        jax.block_until_ready(fn(z, w))
-        best = min(best, time.perf_counter() - t0)
-    return best
-
-
 def _load_file_cache() -> None:
     path = _STATE.cache_path or os.environ.get("REPRO_AUTOTUNE_CACHE")
     _STATE.file_loaded = True
@@ -209,8 +181,8 @@ def pick_tiles(batch: int, kappa: int, d: int, *, kind: str = "delta",
     """Tuned (bm, bk) for one kernel shape — THE entry point.
 
     ``off`` returns the legacy fixed tiles.  Otherwise the config comes
-    from the cache (file-backed if configured) or is computed once: model
-    pick in ``cache`` mode, model-ranked measurement in ``search`` mode.
+    from the cache (file-backed if configured) or is the model's pick,
+    computed once and cached.
     """
     if _STATE.mode == "off":
         return TileConfig(*DEFAULT_TILES)
@@ -224,22 +196,9 @@ def pick_tiles(batch: int, kappa: int, d: int, *, kind: str = "delta",
         hit = _STATE.cache.get(key)
         if hit is not None:
             return hit
-        mode = _STATE.mode
-    # rank (and in search mode, measure) OUTSIDE the lock: _measure runs
-    # jitted kernels whose wrappers may consult the tuner for OTHER keys —
-    # holding a non-reentrant lock across that is a deadlock
-    cands = _rank(_candidates(batch, kappa, d, budget_bytes=budget,
-                              dtype_bytes=dtype_bytes),
-                  batch, kappa, d, dtype_bytes)
-    best = cands[0]
-    if mode == "search" and len(cands) > 1:
-        timed = [(_measure(c, batch, kappa, d), i, c)
-                 for i, c in enumerate(cands[:SEARCH_TOP_N])]
-        best = min(timed)[2]
-    with _STATE.lock:
-        hit = _STATE.cache.get(key)
-        if hit is not None:        # a racing thread resolved it first
-            return hit
+        best = _rank(_candidates(batch, kappa, d, budget_bytes=budget,
+                                 dtype_bytes=dtype_bytes),
+                     batch, kappa, d, dtype_bytes)[0]
         _STATE.searches += 1
         _STATE.cache[key] = best
         _save_file_cache()

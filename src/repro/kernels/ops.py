@@ -219,78 +219,43 @@ def distortion(z: jax.Array, w: jax.Array, *, bm: int | None = None,
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bk", "interpret"))
-def _delta_via_assign(z: jax.Array, w: jax.Array, *, bm: int, bk: int,
-                      interpret: bool | None) -> tuple[jax.Array, jax.Array]:
-    """(counts, zsum) through the blocked assignment kernel + a segment sum.
-
-    The pre-fusion blocked route: the assignments round-trip through HBM and
-    the scatter-add back to (kappa, d) happens in XLA.  Kept as
-    ``vq_delta_routed``'s ``fused=False`` comparator.
-    """
-    assign, _ = vq_assign(z, w, bm=bm, bk=bk, interpret=interpret)
-    kappa, d = w.shape
-    z32 = z.astype(jnp.float32)
-    counts = jnp.zeros((kappa,), jnp.float32).at[assign].add(1.0)
-    zsum = jnp.zeros((kappa, d), jnp.float32).at[assign].add(z32)
-    return counts, zsum
-
-
-@functools.partial(jax.jit, static_argnames=("bm", "bk", "with_delta",
-                                             "interpret"))
-def _vq_delta_blocked(z, w, residual, *, bm: int, bk: int, with_delta: bool,
-                      interpret: bool):
-    batch, d = z.shape
-    kappa = w.shape[0]
+def _vq_delta_blocked(z, w, *, bm: int, bk: int, interpret: bool):
+    batch, kappa = z.shape[0], w.shape[0]
     bm_ = min(bm, max(_bm_floor(interpret), batch))
     zp = _pad_rows(z, bm_)
     wp = _pad_rows(w, bk)
-    bk_ = min(bk, wp.shape[0])
-    if with_delta:
-        rp = _pad_rows(residual.astype(jnp.float32), bk)
-        _, _, counts, zsum, delta = _f.vq_delta_blocked_pallas(
-            zp, wp, bm=bm_, bk=bk_, n_valid=batch, kappa_valid=kappa,
-            residual=rp, interpret=interpret)
-        return counts[:kappa], zsum[:kappa], delta[:kappa]
     _, _, counts, zsum = _f.vq_delta_blocked_pallas(
-        zp, wp, bm=bm_, bk=bk_, n_valid=batch, kappa_valid=kappa,
-        interpret=interpret)
+        zp, wp, bm=bm_, bk=min(bk, wp.shape[0]), n_valid=batch,
+        kappa_valid=kappa, interpret=interpret)
     return counts[:kappa], zsum[:kappa]
 
 
 def vq_delta_blocked(z: jax.Array, w: jax.Array, *, bm: int | None = None,
-                     bk: int | None = None, residual: jax.Array | None = None,
-                     interpret: bool | None = None):
-    """Fused blocked assign+delta (one dispatch, any ``kappa*d``).
-
-    Returns ``(counts, zsum)``; with ``residual`` given, also the in-VMEM
-    displacement epilogue ``counts[:, None]*w - zsum + residual``.
-    """
+                     bk: int | None = None, interpret: bool | None = None
+                     ) -> tuple[jax.Array, jax.Array]:
+    """Fused blocked assign+delta (one dispatch, any ``kappa*d``);
+    returns ``(counts, zsum)``."""
     interpret = _interpret_default() if interpret is None else interpret
     bm, bk = _tiles(z, w, bm, bk, "delta_blocked")
-    return _vq_delta_blocked(z, w, residual, bm=bm, bk=bk,
-                             with_delta=residual is not None,
-                             interpret=interpret)
+    return _vq_delta_blocked(z, w, bm=bm, bk=bk, interpret=interpret)
 
 
 def vq_delta_routed(z: jax.Array, w: jax.Array, *, bm: int | None = None,
                     bk: int | None = None, budget_bytes: int | None = None,
-                    fused: bool = True, interpret: bool | None = None
+                    interpret: bool | None = None
                     ) -> tuple[jax.Array, jax.Array]:
     """``vq_delta`` with VMEM-aware routing (same contract as ``vq_delta``).
 
     When the codebook fits the VMEM budget, the full-codebook kernel runs;
     when ``kappa*d`` is too large, the fused blocked assign+delta kernel
-    keeps everything in one dispatch (``fused=False`` falls back to the
-    pre-fusion blocked assign + XLA segment sum).
+    keeps everything in one dispatch.
     """
     kappa, d = w.shape
     bm, bk = _tiles(z, w, bm, bk, "delta", budget_bytes=budget_bytes)
     if delta_fits_vmem(kappa, d, bm=min(bm, max(8, z.shape[0])),
                        budget_bytes=budget_bytes):
         return vq_delta(z, w, bm=bm, interpret=interpret)
-    if fused:
-        return vq_delta_blocked(z, w, bm=bm, bk=bk, interpret=interpret)
-    return _delta_via_assign(z, w, bm=bm, bk=bk, interpret=interpret)
+    return vq_delta_blocked(z, w, bm=bm, bk=bk, interpret=interpret)
 
 
 def vq_window(zwin: jax.Array, w0: jax.Array, eps: jax.Array, *,
@@ -298,9 +263,9 @@ def vq_window(zwin: jax.Array, w0: jax.Array, eps: jax.Array, *,
     """One fused window: tau sequential eq.-1 steps in a single dispatch.
 
     Bit-identical to scanning ``vq_assign`` and the one-row eq.-1 update
-    over the rows of ``zwin``, the engine's per-step route (the engine
-    gates this).  Callers check ``window_fits_vmem`` first — the codebook
-    stays resident throughout.
+    over the rows of ``zwin``, the engine's per-step route
+    (``tests/test_step_route.py`` pins this).  Callers check
+    ``window_fits_vmem`` first — the codebook stays resident throughout.
     """
     interpret = _interpret_default() if interpret is None else interpret
     return _f.vq_window_pallas(zwin, w0, eps, interpret=interpret)
@@ -316,34 +281,6 @@ def pq_window(zwin: jax.Array, w0: jax.Array, eps: jax.Array, *,
     throughout: 4 * d * k bytes, 128 KiB at PQ16x256 on SIFT."""
     interpret = _interpret_default() if interpret is None else interpret
     return _f.pq_window_pallas(zwin, w0, eps, interpret=interpret)
-
-
-def vq_delta_topk(z: jax.Array, w: jax.Array, residual: jax.Array, *,
-                  frac: float, bm: int | None = None, bk: int | None = None,
-                  budget_bytes: int | None = None,
-                  interpret: bool | None = None):
-    """Fused displacement + top-k compression for the sparse transport.
-
-    Computes the eq.-8 displacement with the error-feedback carry folded in
-    (``counts*w - zsum + residual``) and compresses it to the transport's
-    wire payload — ``(vals (k,), idx (k,) i32, new_residual (kappa, d))``,
-    exactly what ``comm.sparse.sparse_allsum`` derives pre-gather, with
-    ``k = max(1, int(frac * kappa * d))`` (the shared convention).  In the
-    blocked regime the displacement never leaves VMEM before selection.
-    """
-    interpret = _interpret_default() if interpret is None else interpret
-    kappa, d = w.shape
-    bm, bk = _tiles(z, w, bm, bk, "delta", budget_bytes=budget_bytes)
-    if delta_fits_vmem(kappa, d, bm=min(bm, max(8, z.shape[0])),
-                       budget_bytes=budget_bytes):
-        counts, zsum = vq_delta(z, w, bm=bm, interpret=interpret)
-        full = (counts[:, None] * w.astype(jnp.float32) - zsum
-                + residual.astype(jnp.float32))
-    else:
-        _, _, full = vq_delta_blocked(z, w, bm=bm, bk=bk, residual=residual,
-                                      interpret=interpret)
-    k = max(1, int(frac * kappa * d))
-    return _f.vq_topk_pallas(full, k, interpret=interpret)
 
 
 def vq_minibatch_step(z: jax.Array, w: jax.Array, eps: jax.Array,

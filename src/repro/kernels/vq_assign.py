@@ -52,7 +52,7 @@ def _assign_kernel(z_ref, w_ref, z2_ref, w2_ref, assign_ref, mind_ref,
     # (bm, bk) distances for this codebook block — MXU matmul + rank-1 terms.
     # The cross term is spelled ``z @ w.T`` (not a dim-1/dim-1 dot_general):
     # XLA:CPU accumulates the two contractions in different orders, and the
-    # engine's bitwise fused-vs-scan gate needs the SAME rounding as the
+    # bitwise window-kernel-vs-scan pins need the SAME rounding as the
     # ``core.vq.squared_distances`` oracle, which writes ``z @ w.T``.
     d2 = z2_ref[...] - 2.0 * (z @ w.T) + w2_ref[...]
 

@@ -1,23 +1,17 @@
 """Fused Pallas kernels for the VQ hot path — one dispatch, no round trips.
 
-Two fusions on top of the ``vq_assign.py`` pair:
+Three kernels on top of the ``vq_assign.py`` pair:
 
   * ``vq_delta_blocked_pallas`` — assignment + delta accumulation (counts,
     zsum, min-dist) in ONE Pallas dispatch for the blocked (``kappa*d`` >
-    VMEM) regime.  The pre-fusion route (``ops._delta_via_assign``) ran the
-    blocked assign kernel, round-tripped the assignments through HBM, and
-    scatter-added in XLA; here the grid is ``(2*kappa_blocks,
-    batch_blocks)`` with the batch axis minor — an outer *distance* sweep
-    (j < K) streams codebook blocks and keeps the running (min, argmin)
-    for the WHOLE batch in two VMEM-resident ``(batch, 1)`` outputs, then
-    an outer *accumulate* sweep (j >= K) re-streams each codebook block and
-    folds every batch block's one-hot contribution into that block's
-    (counts, zsum) — output revisits stay consecutive, so the accumulators
-    live in VMEM until their single flush.  An optional epilogue forms the
-    eq.-8 displacement ``counts*w - zsum + residual`` in VMEM on each
-    codebook block's last visit, so the sparse transport's top-k selection
-    reads the finished payload instead of re-deriving it from two HBM
-    arrays.
+    VMEM) regime: the grid is ``(2*kappa_blocks, batch_blocks)`` with the
+    batch axis minor — an outer *distance* sweep (j < K) streams codebook
+    blocks and keeps the running (min, argmin) for the WHOLE batch in two
+    VMEM-resident ``(batch, 1)`` outputs, then an outer *accumulate* sweep
+    (j >= K) re-streams each codebook block and folds every batch block's
+    one-hot contribution into that block's (counts, zsum) — output
+    revisits stay consecutive, so the accumulators live in VMEM until
+    their single flush.
 
   * ``vq_window_pallas`` — the engine's inner loop: ``tau`` SEQUENTIAL
     eq.-1 steps (batch of one point each) fused into one dispatch with the
@@ -27,8 +21,8 @@ Two fusions on top of the ``vq_assign.py`` pair:
     the winning row alone), so the fused window is bit-identical to the
     per-step scan it replaces — every per-row reduction and product is
     independent of the seven padding rows the per-step kernel carries.
-    That bit-stability is gated by the engine benchmark's
-    fused-vs-unfused records.
+    ``tests/test_step_route.py`` and ``tests/test_autotune.py`` pin that
+    on XLA:CPU.
 
   * ``pq_window_pallas`` — the same window for a product quantizer: m
     sub-codebooks of k codes over d/m-dimensional sub-vectors, all
@@ -50,8 +44,9 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.vq_assign import BIG
 
 
-def _fused_delta_kernel(z_ref, w_ref, *refs, bm: int, bk: int, kb: int,
-                        n_valid: int, kappa_valid: int, with_delta: bool):
+def _fused_delta_kernel(z_ref, w_ref, assign_ref, mind_ref, counts_ref,
+                        zsum_ref, *, bm: int, bk: int, kb: int, n_valid: int,
+                        kappa_valid: int):
     """Grid = (2*kb, batch_blocks); batch is the minor axis.
 
     Outer steps j < kb:   distance sweep — codebook block j vs batch block
@@ -59,17 +54,10 @@ def _fused_delta_kernel(z_ref, w_ref, *refs, bm: int, bk: int, kb: int,
                           (batch, 1) outputs.
     Outer steps j >= kb:  accumulate sweep — codebook block j-kb gathers
                           counts/zsum from every batch block i (consecutive
-                          revisits of one (bk, ·) output block), plus the
-                          optional in-VMEM delta epilogue at i == last.
+                          revisits of one (bk, ·) output block).
     """
-    if with_delta:
-        res_ref, assign_ref, mind_ref, counts_ref, zsum_ref, delta_ref = refs
-    else:
-        res_ref = delta_ref = None
-        assign_ref, mind_ref, counts_ref, zsum_ref = refs
     j = pl.program_id(0)
     i = pl.program_id(1)
-    nb = pl.num_programs(1)
 
     @pl.when(jnp.logical_and(j == 0, i == 0))
     def _init_running():
@@ -120,26 +108,15 @@ def _fused_delta_kernel(z_ref, w_ref, *refs, bm: int, bk: int, kb: int,
             onehot, z, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-        if with_delta:
-            @pl.when(i == nb - 1)
-            def _delta_epilogue():
-                # eq.-8 displacement + error-feedback carry, formed in VMEM
-                # on this codebook block's LAST visit — the top-k selection
-                # downstream reads a finished payload
-                w = w_ref[...].astype(jnp.float32)
-                delta_ref[...] = (counts_ref[...] * w - zsum_ref[...]
-                                  + res_ref[...])
-
 
 def vq_delta_blocked_pallas(z: jax.Array, w: jax.Array, *, bm: int, bk: int,
                             n_valid: int | None = None,
                             kappa_valid: int | None = None,
-                            residual: jax.Array | None = None,
                             interpret: bool = False):
     """Fused blocked assign+delta: one dispatch for any ``kappa * d``.
 
     (batch, d), (kappa, d) -> (assign (batch,) i32, mind (batch,) f32,
-    counts (kappa,) f32, zsum (kappa, d) f32[, delta (kappa, d) f32]).
+    counts (kappa,) f32, zsum (kappa, d) f32).
     ``batch % bm == 0`` and ``kappa % bk == 0`` required (``ops.py`` pads).
     The residency plan holds only ``O(bm*d + bk*d + bm*bk + batch)`` bytes
     — never the full codebook — which is what ``ops.delta_vmem_bytes(...,
@@ -150,91 +127,31 @@ def vq_delta_blocked_pallas(z: jax.Array, w: jax.Array, *, bm: int, bk: int,
     n_valid = batch if n_valid is None else n_valid
     kappa_valid = kappa if kappa_valid is None else kappa_valid
     kb = kappa // bk
-    with_delta = residual is not None
 
-    grid = (2 * kb, batch // bm)
-    in_specs = [
-        pl.BlockSpec((bm, d), lambda j, i: (i, 0)),
-        pl.BlockSpec((bk, d), lambda j, i: (j % kb, 0)),
-    ]
-    out_specs = [
-        pl.BlockSpec((batch, 1), lambda j, i: (0, 0)),
-        pl.BlockSpec((batch, 1), lambda j, i: (0, 0)),
-        pl.BlockSpec((bk, 1), lambda j, i: (jnp.maximum(j - kb, 0), 0)),
-        pl.BlockSpec((bk, d), lambda j, i: (jnp.maximum(j - kb, 0), 0)),
-    ]
-    out_shape = [
-        jax.ShapeDtypeStruct((batch, 1), jnp.int32),
-        jax.ShapeDtypeStruct((batch, 1), jnp.float32),
-        jax.ShapeDtypeStruct((kappa, 1), jnp.float32),
-        jax.ShapeDtypeStruct((kappa, d), jnp.float32),
-    ]
-    inputs = (z, w)
-    if with_delta:
-        in_specs.append(
-            pl.BlockSpec((bk, d), lambda j, i: (jnp.maximum(j - kb, 0), 0)))
-        out_specs.append(
-            pl.BlockSpec((bk, d), lambda j, i: (jnp.maximum(j - kb, 0), 0)))
-        out_shape.append(jax.ShapeDtypeStruct((kappa, d), jnp.float32))
-        inputs += (residual.astype(jnp.float32),)
-
-    out = pl.pallas_call(
+    assign, mind, counts, zsum = pl.pallas_call(
         functools.partial(_fused_delta_kernel, bm=bm, bk=bk, kb=kb,
-                          n_valid=n_valid, kappa_valid=kappa_valid,
-                          with_delta=with_delta),
+                          n_valid=n_valid, kappa_valid=kappa_valid),
         name="vq_delta_blocked",
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(*inputs)
-    if with_delta:
-        assign, mind, counts, zsum, delta = out
-        return assign[:, 0], mind[:, 0], counts[:, 0], zsum, delta
-    assign, mind, counts, zsum = out
-    return assign[:, 0], mind[:, 0], counts[:, 0], zsum
-
-
-def _topk_kernel(full_ref, vals_ref, idx_ref, res_ref, *, k: int):
-    """Top-k delta compression: the ``sparse_allsum`` per-leaf selection
-    (k largest-|.| entries, error-feedback residual) applied in VMEM to a
-    finished ``(kappa, d)`` displacement, so the sparse transport's wire
-    payload (vals, idx) leaves the kernel directly."""
-    full = full_ref[...].astype(jnp.float32)
-    flat = full.reshape(-1)
-    _, idx = jax.lax.top_k(jnp.abs(flat), k)
-    vals = flat[idx]
-    kept = jnp.zeros_like(flat).at[idx].set(vals)
-    vals_ref[...] = vals[None, :]
-    idx_ref[...] = idx.astype(jnp.int32)[None, :]
-    res_ref[...] = (flat - kept).reshape(full.shape)
-
-
-def vq_topk_pallas(full: jax.Array, k: int, *, interpret: bool = False):
-    """(kappa, d) -> (vals (k,), idx (k,) i32, new_residual (kappa, d)).
-
-    Matches ``comm.sparse.sparse_allsum``'s pre-gather compute bit-for-bit:
-    same ``lax.top_k`` tie order, same scatter/subtract error feedback.
-    """
-    kappa, d = full.shape
-    vals, idx, res = pl.pallas_call(
-        functools.partial(_topk_kernel, k=k),
-        grid=(1,),
-        in_specs=[pl.BlockSpec((kappa, d), lambda i: (0, 0))],
+        grid=(2 * kb, batch // bm),
+        in_specs=[
+            pl.BlockSpec((bm, d), lambda j, i: (i, 0)),
+            pl.BlockSpec((bk, d), lambda j, i: (j % kb, 0)),
+        ],
         out_specs=[
-            pl.BlockSpec((1, k), lambda i: (0, 0)),
-            pl.BlockSpec((1, k), lambda i: (0, 0)),
-            pl.BlockSpec((kappa, d), lambda i: (0, 0)),
+            pl.BlockSpec((batch, 1), lambda j, i: (0, 0)),
+            pl.BlockSpec((batch, 1), lambda j, i: (0, 0)),
+            pl.BlockSpec((bk, 1), lambda j, i: (jnp.maximum(j - kb, 0), 0)),
+            pl.BlockSpec((bk, d), lambda j, i: (jnp.maximum(j - kb, 0), 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((1, k), jnp.float32),
-            jax.ShapeDtypeStruct((1, k), jnp.int32),
+            jax.ShapeDtypeStruct((batch, 1), jnp.int32),
+            jax.ShapeDtypeStruct((batch, 1), jnp.float32),
+            jax.ShapeDtypeStruct((kappa, 1), jnp.float32),
             jax.ShapeDtypeStruct((kappa, d), jnp.float32),
         ],
         interpret=interpret,
-    )(full)
-    return vals[0], idx[0], res
+    )(z, w)
+    return assign[:, 0], mind[:, 0], counts[:, 0], zsum
 
 
 def _window_kernel(z_ref, w0_ref, eps_ref, wout_ref, w2_ref, *, tau: int):
@@ -256,8 +173,8 @@ def _window_kernel(z_ref, w0_ref, eps_ref, wout_ref, w2_ref, *, tau: int):
     lowers a dynamic ref index, not a ``dynamic_slice`` of a loaded value.
 
     Bitwise equality with the per-step scan is load-bearing on XLA:CPU (the
-    engine CI gate and the mesh-vs-oracle tier-1 pins both ride on it), and
-    two compilation artifacts can silently break it:
+    mesh-vs-oracle tier-1 pins ride on it), and two compilation artifacts
+    can silently break it:
 
       * SHAPES: XLA's reduction/matmul emission is shape-dependent, so the
         distance ops here must see the SAME shapes as ``_assign_kernel``

@@ -500,13 +500,11 @@ def main(argv=None) -> int:
                          "collective bytes, and the host residual; prints "
                          "the attribution table and writes the Profiler "
                          "export (render with repro.obs.report --profile)")
-    ap.add_argument("--autotune", choices=("off", "cache", "search"),
+    ap.add_argument("--autotune", choices=("off", "cache"),
                     default="cache",
                     help="Pallas tile selection: 'off' pins the legacy "
                          "(128, 128) tiles, 'cache' picks per shape from "
-                         "the roofline model (memoized), 'search' also "
-                         "times the top model candidates and keeps the "
-                         "fastest")
+                         "the roofline model (memoized)")
     ap.add_argument("--autotune-cache", default="", metavar="TILES.json",
                     help="persist tuned tile configs to this JSON file "
                          "(also read at startup; keyed by shape AND device "

@@ -1,16 +1,19 @@
 """The engine's per-step route (``mesh._step_window``): one ``vq_assign``
 dispatch a step against carried row norms, and only the winning row and
 its norm updated.  It is the route of codebooks past the window kernel's
-VMEM budget, and of ``fused=False``; on XLA:CPU it is pinned bitwise to
-the sequential oracle (``vq.vq_run``) and to the window kernel."""
+VMEM budget; on XLA:CPU it is pinned bitwise to the sequential oracle
+(``vq.vq_run``) and to the window kernel.  Also pinned here: which Pallas
+kernels each of the engine's routes traces."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.extend import core as jcore
+from jax.sharding import Mesh
 
 from repro.core import vq
+from repro.engine import InstantNetwork, MeshExecutor
 from repro.engine import mesh as mesh_lib
 from repro.kernels import ops
 
@@ -37,10 +40,10 @@ def _step_budget(kappa, d):
     return budget
 
 
-def _windows(w0, z, *, budget, fused=True):
+def _windows(w0, z, *, budget):
     f = jax.jit(lambda w, zw, t: mesh_lib._local_window(
-        w, zw, t, eps0=0.5, decay=1.0, use_pallas=True, vmem_budget=budget,
-        fused=fused)[1])
+        w, zw, t, eps0=0.5, decay=1.0, use_pallas=True,
+        vmem_budget=budget)[1])
     w = w0
     for i in range(z.shape[0] // TAU):
         w = f(w, z[i * TAU:(i + 1) * TAU], jnp.int32(i * TAU))
@@ -80,11 +83,9 @@ def test_step_route_bitwise_matches_oracle_and_window_kernel(kappa, d, ties):
     w_step = _windows(w0, z, budget=budget)
     w_oracle = vq.vq_run(w0, z).w
     w_window = _windows(w0, z, budget=None)
-    w_unfused = _windows(w0, z, budget=None, fused=False)
     # the same float operations on every route: BITWISE, not allclose
     assert np.array_equal(np.asarray(w_step), np.asarray(w_oracle))
     assert np.array_equal(np.asarray(w_step), np.asarray(w_window))
-    assert np.array_equal(np.asarray(w_step), np.asarray(w_unfused))
 
 
 @pytest.mark.parametrize("ties", [False, True])
@@ -116,12 +117,54 @@ def test_carried_norms_equal_recomputed_after_every_window():
         assert np.array_equal(np.asarray(w2), np.asarray(norms(w)))
 
 
-def test_step_program_runs_assign_not_delta():
-    """One ``vq_assign`` call a step, the whole codebook as one block (a
-    grid of one), and no ``vq_delta``."""
+class _Traced(Exception):
+    pass
+
+
+def _program_kernels(scheme, w0, *, budget=None):
+    """(name, grid) of every Pallas call in the program the executor
+    builds for one run of ``scheme`` on a one-device mesh (traced only)."""
+    d = w0.shape[0] * w0.shape[2] if w0.ndim == 3 else w0.shape[1]
+    ex = MeshExecutor(mesh=Mesh(np.array(jax.devices()[:1]), ("workers",)),
+                      network=InstantNetwork(), vmem_budget_bytes=budget)
+    kernels = []
+
+    def trace_only(cache_key, build, *args):
+        kernels.extend(_kernels(jax.make_jaxpr(build())(*args)))
+        raise _Traced
+
+    ex._call_compiled = trace_only
+    with pytest.raises(_Traced):
+        ex.run(scheme, w0, jnp.zeros((1, 2 * TAU, d)), jnp.zeros((1, 8, d)),
+               tau=TAU)
+    return kernels
+
+
+@pytest.mark.parametrize("route", ["window", "per_step", "pq",
+                                   "async_in_budget", "async_past_budget"])
+def test_step_program_runs_assign_not_delta(route):
+    """Each engine route traces its one kernel: the window kernel while a
+    window fits the VMEM budget; past it one ``vq_assign`` call a step,
+    the whole codebook as one block (a grid of one), and no ``vq_delta``;
+    the PQ window for sub-codebooks; and on the async tick the
+    full-codebook delta kernel, or the blocked one past its budget."""
     kappa, d = 1024, 128
-    kernels = _step_kernels(kappa, d, _step_budget(kappa, d))
-    assert kernels == [("vq_assign", (1, 1))]
+    w0 = jnp.zeros((kappa, d))
+    delta_budget = ops.delta_vmem_bytes(kappa, d, bm=8)
+    scheme, w0, budget, want = {
+        "window": ("delta", w0, None, [("vq_window", (1,))]),
+        "per_step": ("delta", w0, _step_budget(kappa, d),
+                     [("vq_assign", (1, 1))]),
+        "pq": ("delta", jnp.zeros((16, 256, 8)), None, [("pq_window", (1,))]),
+        "async_in_budget": ("async_delta", w0, delta_budget,
+                            [("vq_delta", (1,))]),
+        "async_past_budget": ("async_delta", w0, delta_budget - 1,
+                              [("vq_delta_blocked", None)]),
+    }[route]
+    kernels = _program_kernels(scheme, w0, budget=budget)
+    assert [name for name, _ in kernels] == [name for name, _ in want]
+    for (_, grid), (_, want_grid) in zip(kernels, want):
+        assert want_grid is None or grid == want_grid
 
 
 def test_assign_with_kept_norms_matches_plain_assign():
