@@ -110,9 +110,7 @@ def _local_window(w0: jax.Array, zwin: jax.Array, t0: jax.Array, *,
             w, _ = jax.lax.scan(lambda w, x: (w - x[1] * vq.pq_H(x[0], w),
                                               None), w0, (zwin, eps))
         return w0 - w, w
-    kappa, d = w0.shape
-    if use_pallas and ops.window_fits_vmem(kappa, d, tau,
-                                           budget_bytes=vmem_budget):
+    if _window_routed(w0.shape, tau, use_pallas, vmem_budget):
         # whole window in ONE Pallas dispatch: tau steps with the codebook
         # VMEM-resident, eliminating tau-1 per-step kernel launches — the
         # step schedule is precomputed (it depends only on t0) and the
@@ -135,6 +133,14 @@ def _local_window(w0: jax.Array, zwin: jax.Array, t0: jax.Array, *,
     w, _ = _step_window(w0, zwin, t0, eps0=eps0, decay=decay,
                         vmem_budget=vmem_budget)
     return w0 - w, w
+
+
+def _window_routed(shape: tuple, tau: int, use_pallas: bool,
+                   vmem_budget: int | None) -> bool:
+    """Does ``_local_window`` run a (kappa, d) codebook through the
+    ``vq_window`` kernel?"""
+    return (use_pallas and len(shape) == 2
+            and ops.window_fits_vmem(*shape, tau, budget_bytes=vmem_budget))
 
 
 def _step_window(w0: jax.Array, zwin: jax.Array, t0: jax.Array, *,
@@ -705,7 +711,9 @@ class MeshExecutor:
                 kappa=w0.shape[-2],
                 subspaces=w0.shape[0] if w0.ndim == 3 else 1,
                 tau=tau, n_eval=eval_data.shape[1],
-                compiled=freshly_compiled)
+                compiled=freshly_compiled,
+                window_body=(ops.window_body() if _window_routed(
+                    w0.shape, tau, use_pallas, vmem_budget) else None))
         trig = None
         if observe and dynamic:
             w_final, curve, divergence, trig, ms_out = out

@@ -264,11 +264,18 @@ def vq_window(zwin: jax.Array, w0: jax.Array, eps: jax.Array, *,
 
     Bit-identical to scanning ``vq_assign`` and the one-row eq.-1 update
     over the rows of ``zwin``, the engine's per-step route
-    (``tests/test_step_route.py`` pins this).  Callers check
+    (``tests/test_step_route.py`` pins this on XLA:CPU).  The body
+    follows the backend (``window_body``).  Callers check
     ``window_fits_vmem`` first — the codebook stays resident throughout.
     """
     interpret = _interpret_default() if interpret is None else interpret
     return _f.vq_window_pallas(zwin, w0, eps, interpret=interpret)
+
+
+def window_body() -> str:
+    """The body ``vq_window`` compiles on this backend:
+    ``"block_refresh"`` on a TPU, ``"interpret"`` elsewhere."""
+    return _f.window_body(_interpret_default())
 
 
 def pq_window(zwin: jax.Array, w0: jax.Array, eps: jax.Array, *,

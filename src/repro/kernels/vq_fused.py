@@ -22,7 +22,10 @@ Three kernels on top of the ``vq_assign.py`` pair:
     per-step scan it replaces — every per-row reduction and product is
     independent of the seven padding rows the per-step kernel carries.
     ``tests/test_step_route.py`` and ``tests/test_autotune.py`` pin that
-    on XLA:CPU.
+    on XLA:CPU for the interpret body.  Compiled by Mosaic, the kernel
+    pushes the codebook into the MXU once a window and re-scores only the
+    128-code block a step changed; on a TPU v5e its codebooks are the
+    per-step body's bit for bit (see ``_window_kernel``).
 
   * ``pq_window_pallas`` — the same window for a product quantizer: m
     sub-codebooks of k codes over d/m-dimensional sub-vectors, all
@@ -154,27 +157,62 @@ def vq_delta_blocked_pallas(z: jax.Array, w: jax.Array, *, bm: int, bk: int,
     return assign[:, 0], mind[:, 0], counts[:, 0], zsum
 
 
-def _window_kernel(z_ref, w0_ref, eps_ref, wout_ref, w2_ref, *, tau: int):
+BLOCK_REFRESH = "block_refresh"
+INTERPRET = "interpret"
+
+
+def window_body(interpret: bool) -> str:
+    """The window kernel's body for a backend: kept products refreshed
+    one block at a time when Mosaic compiles it, the plain per-step
+    ``z @ w.T`` under interpret (XLA:CPU)."""
+    return INTERPRET if interpret else BLOCK_REFRESH
+
+
+def _window_kernel(z_ref, w0_ref, eps_ref, wout_ref, *refs, tau: int):
     """One fused window: tau sequential eq.-1 steps, codebook VMEM-resident.
 
     z_ref:   (tau, d)    the window's point stream
     w0_ref:  (kappa, d)  prototypes entering the window
     eps_ref: (tau,)      precomputed Robbins-Monro steps (f32, in SMEM)
     wout_ref:(kappa, d)  prototypes after the window; the resident codebook
+    refs:    (p_ref, w2_ref) in the block-refresh body, (w2_ref,) in the
+             interpret body
+    p_ref:   (tau, kappa) VMEM scratch: every point's products with the
+             resident codebook
     w2_ref:  (1, kappa)  VMEM scratch: the resident rows' squared norms
 
     The codebook is copied into ``wout_ref`` and its row norms into
     ``w2_ref`` once, at window entry.  A step then touches one row: it
     scores its point against the resident codebook with the kept norms,
     takes the winning code as a scalar, loads that row, updates it, stores
-    it back, and refreshes that row's norm with a lane select.  Nothing of
-    (kappa, d) size is formed per step beyond the distance product.  The
-    step reads its point and step size from the refs at ``t``: Mosaic
-    lowers a dynamic ref index, not a ``dynamic_slice`` of a loaded value.
+    it back, and refreshes that row's norm with a lane select.  The step
+    reads its point and step size from the refs at ``t``: Mosaic lowers a
+    dynamic ref index, not a ``dynamic_slice`` of a loaded value.
 
-    Bitwise equality with the per-step scan is load-bearing on XLA:CPU (the
-    mesh-vs-oracle tier-1 pins ride on it), and two compilation artifacts
-    can silently break it:
+    Two bodies, chosen by ``window_body`` from the backend:
+
+      * BLOCK REFRESH (compiled by Mosaic): the MXU takes a product's
+        right operand as weights, pushed one vreg at a time, so a step's
+        ``z @ w.T`` pushes the whole codebook (128 vregs at kappa=1,024,
+        d=128) to score one point.  Here the codebook is pushed once at
+        window entry against all tau points, ``p = zs @ w0.T``, and a step
+        reads its row of ``p``.  A step changes one row, so after the
+        update only the 128-code block that holds it is pushed again and
+        re-scored for all tau points.  Each kept product is the MXU dot
+        of the same point and the same row that the per-step product
+        computes, so on a TPU v5e the codebooks are bit for bit those of
+        the per-step ``z @ w.T`` body compiled there (checked after the
+        first three chunks of ``sift1m-ivf1024.train`` at three seeds,
+        PERF.md section 6).  The entry norms are the same lane sums, moved onto lanes by XLU
+        transposes of their (kappa, 128) broadcast instead of one scalar
+        at a time.  kappa that is not a whole number of 128-code blocks
+        re-scores the whole codebook each step.
+      * INTERPRET (XLA:CPU): the cross term is each step's ``z @ w.T``,
+        so the bitwise contract below holds on the interpret backend.
+
+    Bitwise equality of the interpret body with the per-step scan is
+    load-bearing on XLA:CPU (the mesh-vs-oracle tier-1 pins ride on it),
+    and two compilation artifacts can silently break it:
 
       * SHAPES: XLA's reduction/matmul emission is shape-dependent, so the
         distance ops here must see the SAME shapes as ``_assign_kernel``
@@ -197,17 +235,27 @@ def _window_kernel(z_ref, w0_ref, eps_ref, wout_ref, w2_ref, *, tau: int):
     that returns ``w`` unchanged, on the winning row it is
     ``w - eps*(w - z)``.
     """
+    *kept, w2_ref = refs
     kappa = w0_ref.shape[0]
+    block = 128 if kappa % 128 == 0 else kappa
     w0 = w0_ref[...].astype(jnp.float32)
     wout_ref[...] = w0
-    w2_ref[...] = jnp.sum(w0 * w0, axis=1)[None, :]
+    if kept and block == 128:
+        w2 = jnp.sum(w0 * w0, axis=1, keepdims=True)             # (kappa, 1)
+        w2_ref[...] = jnp.broadcast_to(w2, (kappa, 128)).T[0:1, :]
+    else:
+        w2_ref[...] = jnp.sum(w0 * w0, axis=1)[None, :]
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, kappa), 1)
+    if kept:
+        (p_ref,) = kept
+        zs = z_ref[...].astype(jnp.float32)                      # (tau, d)
+        p_ref[...] = zs @ w0.T                                   # (tau, kappa)
 
     def step(t, carry):
         z = z_ref[pl.ds(t, 1), :].astype(jnp.float32)            # (1, d)
         z2 = jnp.sum(z * z, axis=1, keepdims=True)               # (1, 1)
-        w = wout_ref[...]
-        d2 = z2 - 2.0 * (z @ w.T) + w2_ref[...]                  # (1, kappa)
+        zw = p_ref[pl.ds(t, 1), :] if kept else z @ wout_ref[...].T
+        d2 = z2 - 2.0 * zw + w2_ref[...]                         # (1, kappa)
         arg = jnp.argmin(d2, axis=1, keepdims=True)              # (1, 1)
         k = arg[0, 0]
         row = wout_ref[pl.ds(k, 1), :]                           # (1, d)
@@ -217,6 +265,11 @@ def _window_kernel(z_ref, w0_ref, eps_ref, wout_ref, w2_ref, *, tau: int):
         w2_ref[...] = jnp.where(lane == arg,
                                 jnp.sum(row * row, axis=1, keepdims=True),
                                 w2_ref[...])
+        if kept:
+            base = (pl.multiple_of(k // block * block, block)
+                    if kappa > block else 0)
+            p_ref[:, pl.ds(base, block)] = (
+                zs @ wout_ref[pl.ds(base, block), :].T)
         return carry
 
     jax.lax.fori_loop(0, tau, step, 0)
@@ -224,9 +277,16 @@ def _window_kernel(z_ref, w0_ref, eps_ref, wout_ref, w2_ref, *, tau: int):
 
 def vq_window_pallas(zwin: jax.Array, w0: jax.Array, eps: jax.Array, *,
                      interpret: bool = False) -> jax.Array:
-    """(tau, d), (kappa, d), (tau,) -> w after tau fused sequential steps."""
+    """(tau, d), (kappa, d), (tau,) -> w after tau fused sequential steps.
+
+    The body follows ``interpret`` (``window_body``): compiled by Mosaic
+    the kernel gets the (tau, kappa) products as a scratch and keeps them
+    block by block."""
     tau, d = zwin.shape
     kappa, _ = w0.shape
+    scratch = [pltpu.VMEM((1, kappa), jnp.float32)]
+    if window_body(interpret) == BLOCK_REFRESH:
+        scratch.insert(0, pltpu.VMEM((tau, kappa), jnp.float32))
     return pl.pallas_call(
         functools.partial(_window_kernel, tau=tau),
         name="vq_window",
@@ -238,7 +298,7 @@ def vq_window_pallas(zwin: jax.Array, w0: jax.Array, eps: jax.Array, *,
         ],
         out_specs=pl.BlockSpec((kappa, d), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((kappa, d), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((1, kappa), jnp.float32)],
+        scratch_shapes=scratch,
         interpret=interpret,
     )(zwin, w0.astype(jnp.float32), eps.reshape(tau).astype(jnp.float32))
 

@@ -105,17 +105,21 @@ class Profiler:
     def note_segment(self, *, program: Any, scheme: str, transport: str,
                      topology: str, m: int, n_windows: int, d: int,
                      kappa: int, tau: int, n_eval: int = 0,
-                     compiled: bool = False, subspaces: int = 1) -> None:
+                     compiled: bool = False, subspaces: int = 1,
+                     window_body: str | None = None) -> None:
         """Report one executed segment's shapes (a whole run for the fixed-M
         executor; one per-M slice for an elastic run).  A product quantizer
         reports its codes per sub-codebook as ``kappa`` and its number of
         sub-codebooks as ``subspaces``: a step's search is then 2 * kappa
-        * d operations, as a (kappa, d) codebook's is."""
+        * d operations, as a (kappa, d) codebook's is.  A segment on the
+        ``vq_window`` route names the kernel body it compiled
+        (``ops.window_body``); other routes leave it ``None``."""
         self._pending.append(dict(
             program=str(program), scheme=scheme, transport=transport,
             topology=topology, m=int(m), n_windows=max(int(n_windows), 1),
             d=int(d), kappa=int(kappa), tau=int(tau), n_eval=int(n_eval),
-            compiled=bool(compiled), subspaces=int(subspaces)))
+            compiled=bool(compiled), subspaces=int(subspaces),
+            window_body=window_body))
 
     def finish_run(self, wall_s: float) -> dict | None:
         """Attribute one run's measured wall across the pending segments.
@@ -176,6 +180,7 @@ class Profiler:
             "window_hbm_bytes": hbm,
             "collective_bytes_per_window": coll_bytes,
             "compiled_in_run": any(s["compiled"] for s in segs),
+            "window_body": first["window_body"],
             "peaks": dataclasses.asdict(self.peaks),
         }
         self.attributions.append(rec)

@@ -13,6 +13,7 @@ Three contracts pinned here:
     never from scattered hardcoded constants.
 """
 
+import functools
 import pathlib
 import re
 
@@ -20,9 +21,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import vq
-from repro.kernels import autotune, ops, ref
+from repro.kernels import autotune, ops, ref, vq_fused
 
 KEY = jax.random.PRNGKey(11)
 
@@ -77,25 +80,27 @@ def test_vq_assign_autotuned_matches_ref(batch, kappa, d):
                                rtol=1e-4, atol=1e-4)
 
 
-def _window_case(case):
+def _window_case(case, kappa=None):
     """(zwin, w0) for one window-kernel parity case.
 
     ``normal``: the original small case.  ``uniform``: the training cells'
     width on [0, 1] data.  ``repeat``: four codes for 64 points, so the
     same rows win again and again and their kept norms are read after
     their own update.  ``tie``: every codebook row twice, so each step's
-    argmin is a tie the first index must win."""
+    argmin is a tie the first index must win.  ``kappa`` sets the number
+    of codes; in ``repeat`` all but four of them sit far from the data."""
     kz, kw = jax.random.split(jax.random.fold_in(KEY, 99))
     if case == "normal":
         return (jax.random.normal(kz, (12, 8)),
-                jax.random.normal(kw, (16, 8)))
+                jax.random.normal(kw, (kappa or 16, 8)))
     if case == "uniform":
         return (jax.random.uniform(kz, (10, 128)),
-                jax.random.uniform(kw, (1024, 128)))
+                jax.random.uniform(kw, (kappa or 1024, 128)))
     if case == "repeat":
+        w0 = jax.random.normal(kw, (kappa or 4, 8))
         return (jax.random.normal(kz, (64, 8)),
-                jax.random.normal(kw, (4, 8)))
-    half = jax.random.normal(kw, (8, 8))
+                w0.at[4:].add(100.0))
+    half = jax.random.normal(kw, ((kappa or 16) // 2, 8))
     return jax.random.normal(kz, (10, 8)), jnp.concatenate([half, half])
 
 
@@ -127,6 +132,77 @@ def test_window_kernel_bitwise_matches_per_step_scan(case):
     w_ref = jax.jit(scan_oracle)(zwin, w0, eps)
     # fusion trades dispatches, not math: BITWISE equality, not allclose
     assert np.array_equal(np.asarray(w_fused), np.asarray(w_ref))
+
+
+def _per_step_scan(zwin, w0, eps):
+    """The engine's per-step route, verbatim (``mesh._step_window``'s scan
+    body): the window kernel replays these float ops.  Returns the
+    codebook and each step's winning code."""
+    def body(carry, ze):
+        w, w2 = carry
+        z, e = ze
+        arg, _ = ops.vq_assign_routed(z[None, :], w, w2=w2)
+        k = arg[0]
+        row = jax.lax.dynamic_slice_in_dim(w, k, 1)
+        h = row - z[None, :]
+        row = row - e * h
+        w = jax.lax.dynamic_update_slice_in_dim(w, row, k, axis=0)
+        w2 = jax.lax.dynamic_update_slice_in_dim(
+            w2, jnp.sum(row * row, axis=1, keepdims=True), k, axis=1)
+        return (w, w2), k
+    w2 = jnp.sum(w0 * w0, axis=1)[None, :]
+    (w, _), args = jax.lax.scan(body, (w0, w2), (zwin, eps))
+    return w, args
+
+
+def _block_refresh_window(zwin, w0, eps):
+    """The block-refresh body under interpret, its kept (tau, kappa)
+    products made a second output: (w, p) after the window."""
+    tau, d = zwin.shape
+    kappa = w0.shape[0]
+    whole = lambda i: (0, 0)  # noqa: E731
+    return pl.pallas_call(
+        functools.partial(vq_fused._window_kernel, tau=tau),
+        grid=(1,),
+        in_specs=[pl.BlockSpec((tau, d), whole),
+                  pl.BlockSpec((kappa, d), whole),
+                  pl.BlockSpec(memory_space=pltpu.SMEM)],
+        out_specs=[pl.BlockSpec((kappa, d), whole),
+                   pl.BlockSpec((tau, kappa), whole)],
+        out_shape=[jax.ShapeDtypeStruct((kappa, d), jnp.float32),
+                   jax.ShapeDtypeStruct((tau, kappa), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((1, kappa), jnp.float32)],
+        interpret=True,
+    )(zwin, w0, eps)
+
+
+@pytest.mark.parametrize("kappa", [16, 1024])
+@pytest.mark.parametrize("case", ["normal", "uniform", "repeat", "tie"])
+def test_block_refresh_body_keeps_its_products_in_step(case, kappa):
+    """The body a TPU compiles, run under interpret: its kept products
+    are the final codebook's at window exit, the codebook matches the
+    per-step scan, and every step picks the scan's winner (read as the
+    one row that step changes, with the later steps' sizes set to 0)."""
+    zwin, w0 = _window_case(case, kappa)
+    tau = zwin.shape[0]
+    eps = vq.default_steps(1 + jnp.arange(tau, dtype=jnp.int32))
+    run = jax.jit(_block_refresh_window)
+    w, p = run(zwin, w0, eps)
+    block = min(kappa, 128)
+    want = jax.jit(lambda z, w: jnp.concatenate(
+        [z @ w[b:b + block].T for b in range(0, kappa, block)], axis=1))
+    assert np.array_equal(np.asarray(p), np.asarray(want(zwin, w)))
+    w_ref, args_ref = jax.jit(_per_step_scan)(zwin, w0, eps)
+    np.testing.assert_allclose(np.asarray(w), np.asarray(w_ref), rtol=1e-5)
+
+    args, w_prev = [], np.asarray(w0)
+    for t in range(tau):
+        w_t = np.asarray(run(zwin, w0, jnp.where(jnp.arange(tau) <= t,
+                                                 eps, 0.0))[0])
+        (changed,) = np.nonzero(np.any(w_t != w_prev, axis=1))
+        args.extend(changed.tolist())
+        w_prev = w_t
+    assert args == np.asarray(args_ref).tolist()
 
 
 # -- tuner determinism ------------------------------------------------------

@@ -140,14 +140,17 @@ def _program_kernels(scheme, w0, *, budget=None):
     return kernels
 
 
-@pytest.mark.parametrize("route", ["window", "per_step", "pq",
-                                   "async_in_budget", "async_past_budget"])
+@pytest.mark.parametrize("route", ["window", "per_step", "per_step_4096",
+                                   "pq", "async_in_budget",
+                                   "async_past_budget"])
 def test_step_program_runs_assign_not_delta(route):
     """Each engine route traces its one kernel: the window kernel while a
     window fits the VMEM budget; past it one ``vq_assign`` call a step,
-    the whole codebook as one block (a grid of one), and no ``vq_delta``;
-    the PQ window for sub-codebooks; and on the async tick the
-    full-codebook delta kernel, or the blocked one past its budget."""
+    the whole codebook as one block (a grid of one), and no ``vq_delta``
+    (at kappa=4,096, d=128 under the default budget too: the training
+    cell at that width is not window-routed); the PQ window for
+    sub-codebooks; and on the async tick the full-codebook delta kernel,
+    or the blocked one past its budget."""
     kappa, d = 1024, 128
     w0 = jnp.zeros((kappa, d))
     delta_budget = ops.delta_vmem_bytes(kappa, d, bm=8)
@@ -155,6 +158,8 @@ def test_step_program_runs_assign_not_delta(route):
         "window": ("delta", w0, None, [("vq_window", (1,))]),
         "per_step": ("delta", w0, _step_budget(kappa, d),
                      [("vq_assign", (1, 1))]),
+        "per_step_4096": ("delta", jnp.zeros((4096, d)), None,
+                          [("vq_assign", (1, 1))]),
         "pq": ("delta", jnp.zeros((16, 256, 8)), None, [("pq_window", (1,))]),
         "async_in_budget": ("async_delta", w0, delta_budget,
                             [("vq_delta", (1,))]),
@@ -175,3 +180,26 @@ def test_assign_with_kept_norms_matches_plain_assign():
         a_ref, m_ref = ops.vq_assign(z, w, bm=8, bk=bk)
         assert np.array_equal(np.asarray(a), np.asarray(a_ref))
         assert np.array_equal(np.asarray(m), np.asarray(m_ref))
+
+
+@pytest.mark.parametrize("route", ["window", "per_step", "pq"])
+def test_profiler_notes_which_window_body_ran(route):
+    """A segment on the window route names the kernel body it compiled
+    (the interpret body off a TPU); the per-step and PQ routes name
+    none."""
+    from repro.obs import Profiler
+
+    kappa, d = 16, 8
+    w0, budget, body = {
+        "window": (jnp.zeros((kappa, d)), None, "interpret"),
+        "per_step": (jnp.zeros((kappa, d)), _step_budget(kappa, d), None),
+        "pq": (jnp.zeros((2, kappa, d // 2)), None, None),
+    }[route]
+    prof = Profiler()
+    ex = MeshExecutor(mesh=Mesh(np.array(jax.devices()[:1]), ("workers",)),
+                      network=InstantNetwork(), vmem_budget_bytes=budget,
+                      profiler=prof)
+    z, _ = _case(kappa, d, 2 * TAU, False)
+    ex.run("delta", w0, z[None], z[None, :8], tau=TAU)
+    (rec,) = prof.attributions
+    assert rec["window_body"] == body
